@@ -686,4 +686,6 @@ __all__ = ["CodoOptions", "CompiledProgram", "CompiledTrainStep", "F",
 
 
 if __name__ == "__main__":
+    from repro.core.cache import enable_jax_cache
+    enable_jax_cache()
     raise SystemExit(main())

@@ -33,7 +33,11 @@ serialize) are skipped silently.
 
 Knobs: ``CODO_CACHE_SIZE`` (LRU entries, default 256), ``CODO_CACHE_DIR``
 (enables the disk tier) and ``CODO_CACHE_JSON`` (JSON mirror) — read by
-:func:`repro.core.compiler.default_cache`.
+:func:`repro.core.compiler.default_cache`.  The library default stays
+memory-only; command-line entry points put the disk tier at
+:func:`default_disk_dir` and jax's persistent compilation cache at
+:func:`enable_jax_cache` — both fixed paths inside the checkout, so a
+second run finds what the first one stored.
 """
 
 from __future__ import annotations
@@ -47,6 +51,32 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any
+
+# The checkout this package runs from (src/repro/core/cache.py -> root).
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def default_disk_dir() -> str:
+    """The compile cache's disk tier for command-line entry points:
+    ``CODO_CACHE_DIR`` when set, else ``<checkout>/.codo_cache``."""
+    return os.environ.get("CODO_CACHE_DIR") or str(CHECKOUT / ".codo_cache")
+
+
+def enable_jax_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left to jax
+    (which reads it itself); otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``.  Every compiled program is kept, however
+    fast it compiled, so a warm run compiles nothing."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
 
 # Disk-entry file stem — what :meth:`CompileCache.key` produces:
 # "<sha256 graph hash>-<16-hex options key>".  clear() only touches JSON

@@ -55,7 +55,7 @@ from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from dataclasses import dataclass
 
 from .buffers import BufferPlan
-from .cache import CompileCache, _clone
+from .cache import CompileCache, _clone, default_disk_dir, enable_jax_cache
 from .coarse import CoarseReport
 from .costmodel import V5E, GraphCost, HwParams, graph_latency, sequential_latency
 from .fine import FineReport
@@ -439,8 +439,15 @@ _WORKER_CACHE: CompileCache | None = None
 
 
 def _init_batch_worker(disk_dir: str | None, use_cache: bool,
-                       json_mirror: bool = False) -> None:
+                       json_mirror: bool = False,
+                       backend: str = "cpu") -> None:
+    """Worker set-up.  ``backend`` is the parent's resolved
+    :func:`~repro.core.costmodel.routing_backend`: pinning it here means
+    nothing in a worker ever asks jax for a backend, so a compile-only
+    worker never initializes one (on a TPU host the parent holds the
+    chip, and a child that touched it would fail or hang)."""
     global _WORKER_CACHE
+    os.environ["CODO_BACKEND"] = backend
     _WORKER_CACHE = (CompileCache(disk_dir=disk_dir, json_mirror=json_mirror)
                      if use_cache else None)
 
@@ -454,18 +461,20 @@ def _run_job_in_worker(job: BatchJob) -> BatchResult:
     return res
 
 
-def _mp_context():
+def _mp_context(backend: str):
     """Start method for the batch pool: ``CODO_MP_START`` overrides, else
-    fork where available.  Fork is safe here even with jax imported in the
-    parent (jax warns about forking a threaded process) because workers
-    only build and compile graphs — both jax-free since task numerics are
+    fork where available and the parent holds no accelerator, else spawn.
+    Fork is safe on a CPU backend even with jax imported in the parent
+    (jax warns about forking a threaded process) because workers only
+    build and compile graphs — both jax-free since task numerics are
     declarative specs — and it avoids spawn's per-worker re-import cost
-    (~5 s) and spawn's requirement of an importable ``__main__``.  Set
-    ``CODO_MP_START=spawn`` if a worker ever needs to *execute* jax."""
+    (~5 s).  A parent that resolved an accelerator backend holds its
+    device, whose state a forked child must not inherit: spawn then."""
     method = os.environ.get("CODO_MP_START")
     if not method:
         methods = multiprocessing.get_all_start_methods()
-        method = "fork" if "fork" in methods else "spawn"
+        method = ("fork" if "fork" in methods and backend == "cpu"
+                  else "spawn")
     return multiprocessing.get_context(method)
 
 
@@ -500,13 +509,17 @@ def codo_opt_batch(jobs, *, max_workers: int | None = None,
                 "executor='process' requires picklable jobs (declarative "
                 "specs + module-level graph builders, e.g. batch_workloads); "
                 f"use executor='thread' for closure jobs ({e})") from e
+        from .costmodel import routing_backend
+        backend = routing_backend()
         disk_dir = (str(cache.disk_dir)
                     if cache is not None and cache.disk_dir else None)
         with ProcessPoolExecutor(
-                max_workers=min(workers, len(jobs)), mp_context=_mp_context(),
+                max_workers=min(workers, len(jobs)),
+                mp_context=_mp_context(backend),
                 initializer=_init_batch_worker,
                 initargs=(disk_dir, cache is not None,
-                          bool(cache is not None and cache.json_mirror))) as pool:
+                          bool(cache is not None and cache.json_mirror),
+                          backend)) as pool:
             return list(pool.map(_run_job_in_worker, jobs))
 
     if workers <= 1 or len(jobs) <= 1:
@@ -732,8 +745,10 @@ def main(argv=None) -> int:
     ap.add_argument("--strict", action="store_true",
                     help="with --enforce-budgets: exit non-zero on any "
                          "budget violation")
-    ap.add_argument("--cache-dir", default=os.environ.get("CODO_CACHE_DIR", ".codo_cache"),
-                    help="on-disk compile-cache directory ('' to keep memory-only)")
+    ap.add_argument("--cache-dir", default=default_disk_dir(),
+                    help="on-disk compile-cache directory ('' to keep "
+                         "memory-only; default $CODO_CACHE_DIR, else "
+                         "<checkout>/.codo_cache)")
     ap.add_argument("--no-cache", action="store_true", help="disable caching")
     ap.add_argument("--clear-cache", action="store_true",
                     help="drop existing disk-cache entries first")
@@ -911,4 +926,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_jax_cache()
     raise SystemExit(main())

@@ -61,6 +61,10 @@ class HwParams:
 
 V5E = HwParams()
 
+# The chips the cost model has numbers for, keyed by jax's ``device_kind``
+# (Google Cloud "TPU v5e" documentation: 197 TFLOP/s bf16, 819 GB/s HBM).
+HW_BY_DEVICE_KIND: dict[str, HwParams] = {"TPU v5 lite": V5E}
+
 # Op-level initiation intervals (cycles per innermost iteration at degree 1)
 # — the "profiled basic operation" table of §VI.
 OP_II: dict[str, float] = {
@@ -348,16 +352,32 @@ DEFAULT_ROUTING_PARAMS: dict[str, RoutingCostParams] = {
 
 def routing_backend() -> str:
     """The backend the routing gate prices against: ``CODO_BACKEND`` when
-    set, else jax's default backend, else ``"cpu"`` (jax-less hosts)."""
+    set, else jax's default backend."""
     import os
     env = os.environ.get("CODO_BACKEND", "").strip().lower()
     if env:
         return env
+    import jax
+    return str(jax.default_backend())
+
+
+def device_hw(default: HwParams = V5E) -> HwParams:
+    """The hardware the routing gate prices on this process's device: on a
+    TPU backend the :data:`HW_BY_DEVICE_KIND` entry for
+    ``jax.devices()[0].device_kind`` (an unknown kind raises rather than
+    borrowing another chip's numbers); elsewhere ``default``, the modeled
+    target of the compile options."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return default
+    kind = jax.devices()[0].device_kind
     try:
-        import jax
-        return str(jax.default_backend())
-    except Exception:                        # pragma: no cover — stub builds
-        return "cpu"
+        return HW_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no HwParams for TPU device_kind {kind!r}; known kinds: "
+            f"{sorted(HW_BY_DEVICE_KIND)} (add the chip's published peaks "
+            f"to costmodel.HW_BY_DEVICE_KIND)") from None
 
 
 def calibrate_routing_params(doc: dict,

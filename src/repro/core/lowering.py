@@ -41,6 +41,7 @@ import jax
 import numpy as np
 
 from .compiler import CompiledDataflow
+from .costmodel import device_hw
 from .graph import FIFO, DataflowGraph, GraphError, Task
 from .ops import registry_epoch as _ops_epoch
 from .routing import (XLA_FUSED, KernelPattern, RoutedKernel,
@@ -239,7 +240,6 @@ def _sharded_program(graph: DataflowGraph, steps, outputs: list[str],
     (all_gather) and after the producer that left partial sums
     (psum / reduce_scatter+all_gather / ppermute ring)."""
     from repro.distributed import collectives as _coll
-    from repro.distributed.sharding import shard_map
     from repro.launch.mesh import mesh_from_spec
 
     before, after = _coll.attach(sharding.steps)
@@ -263,8 +263,8 @@ def _sharded_program(graph: DataflowGraph, steps, outputs: list[str],
                 scope[s.buffer] = fns[id(s)](scope[s.buffer])
         return {k: scope[k] for k in outputs}
 
-    mapped = shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                       out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                           out_specs=out_specs, check_vma=False)
 
     def program(env: dict) -> dict:
         extra = set(env) - set(in_specs)
@@ -319,7 +319,8 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
     impl = compiled.buffer_plan.impl if compiled.buffer_plan else {}
     groups = fusion_groups(graph, impl)
     if use_registered_kernels:
-        route_groups(graph, groups, impl, hw=compiled.options.hw)
+        route_groups(graph, groups, impl,
+                     hw=device_hw(compiled.options.hw))
     if sharding is not None:
         _drop_sharded_routes(groups, sharding)
     steps = _build_steps(graph, groups, use_registered_kernels)

@@ -302,7 +302,8 @@ def _conv2d(spec: OpSpec, env: dict) -> dict:
     g = int(spec.attrs.get("groups", 1))
     y = jax.lax.conv_general_dilated(
         env[spec.ins[0]], env[spec.ins[1]], (s, s), "VALID",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"), feature_group_count=g)
+        dimension_numbers=("NCHW", "OIHW", "NCHW"), feature_group_count=g,
+        precision=jax.lax.Precision.HIGHEST)
     return {spec.outs[0]: y}
 
 
@@ -388,17 +389,25 @@ def _softmax(spec: OpSpec, env: dict) -> dict:
     return {spec.outs[0]: jax.nn.softmax(env[spec.ins[0]], axis)}
 
 
+# Contractions (matmul, mv, conv and their VJPs) name their precision: on
+# TPU an f32 contraction with implicit precision is a single bf16 pass.
 @register_op("matmul")
 def _matmul(spec: OpSpec, env: dict) -> dict:
-    return {spec.outs[0]: env[spec.ins[0]] @ env[spec.ins[1]]}
+    import jax
+    import jax.numpy as jnp
+    return {spec.outs[0]: jnp.matmul(env[spec.ins[0]], env[spec.ins[1]],
+                                     precision=jax.lax.Precision.HIGHEST)}
 
 
 @register_op("mv")
 def _mv(spec: OpSpec, env: dict) -> dict:
+    import jax
+    import jax.numpy as jnp
     A = env[spec.ins[0]]
     if spec.attrs.get("trans", False):
         A = A.T
-    return {spec.outs[0]: A @ env[spec.ins[1]]}
+    return {spec.outs[0]: jnp.matmul(A, env[spec.ins[1]],
+                                     precision=jax.lax.Precision.HIGHEST)}
 
 
 @register_op("transpose")
@@ -595,7 +604,7 @@ def _conv2d_input_grad(spec: OpSpec, env: dict) -> dict:
         return jax.lax.conv_general_dilated(
             x, w, (s, s), "VALID",
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            feature_group_count=groups)
+            feature_group_count=groups, precision=jax.lax.Precision.HIGHEST)
 
     _, vjp = jax.vjp(fwd, jnp.zeros(x_shape, g.dtype))
     return {spec.outs[0]: vjp(g)[0]}
@@ -614,7 +623,7 @@ def _conv2d_weight_grad(spec: OpSpec, env: dict) -> dict:
         return jax.lax.conv_general_dilated(
             x, w, (s, s), "VALID",
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            feature_group_count=groups)
+            feature_group_count=groups, precision=jax.lax.Precision.HIGHEST)
 
     _, vjp = jax.vjp(fwd, jnp.zeros(w_shape, g.dtype))
     return {spec.outs[0]: vjp(g)[0]}

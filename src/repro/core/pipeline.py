@@ -94,10 +94,8 @@ def pipeline_fn(stage_fns: Sequence[Callable[[Any, jax.Array], jax.Array]],
         # stages contribute zeros) so out_specs=P() holds
         return jax.lax.psum(outs, axis)
 
-    from ..distributed.sharding import shard_map  # version-compat wrapper
-
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(axis), P()),     # params sharded by stage; x replicated
             out_specs=P(),

@@ -63,6 +63,7 @@ program.
 from __future__ import annotations
 
 import os
+import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
@@ -94,8 +95,20 @@ def pallas_forced() -> bool:
 def pallas_interpret_forced() -> bool:
     """``CODO_PALLAS_INTERPRET=1`` forces routed kernels to run the real
     Pallas body in interpret mode on non-TPU hosts (the CI numerics path).
-    Routing-relevant: enters the lowering memo key like the disable flag."""
-    return _truthy("CODO_PALLAS_INTERPRET")
+    Routing-relevant: enters the lowering memo key like the disable flag.
+
+    On a TPU backend the flag is ignored with a warning: there the
+    compiled kernel is the real path, and an interpreted one would run
+    the kernel body op by op while reporting the chain as routed."""
+    if not _truthy("CODO_PALLAS_INTERPRET"):
+        return False
+    import jax
+    if jax.default_backend() == "tpu":
+        warnings.warn("CODO_PALLAS_INTERPRET is ignored on a TPU backend: "
+                      "routed kernels run compiled", RuntimeWarning,
+                      stacklevel=2)
+        return False
+    return True
 
 
 @dataclass(frozen=True)

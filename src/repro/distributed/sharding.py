@@ -18,7 +18,6 @@ family (dense / MoE / SSM / hybrid / enc-dec) is covered by one table.
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any
 
 import jax
@@ -27,57 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 
-
-def use_mesh(mesh: Mesh):
-    """Version-compat context manager activating ``mesh`` as the ambient
-    mesh.
-
-    Newer JAX exposes ``jax.sharding.use_mesh`` (context manager) or
-    ``jax.set_mesh``; older releases (<= 0.4.x) only have the ``Mesh``
-    object's own context manager.  Callers write ``with use_mesh(m): ...``
-    and get whichever the installed JAX supports.
-    """
-    um = getattr(jax.sharding, "use_mesh", None)
-    if um is not None:
-        return um(mesh)
-    sm = getattr(jax, "set_mesh", None)
-    if sm is not None:
-        try:
-            prev = jax.sharding.get_abstract_mesh()
-        except Exception:
-            prev = None
-        ctx = sm(mesh)
-        if hasattr(ctx, "__enter__"):
-            return ctx
-
-        # set_mesh mutated global state: restore the previous mesh on exit
-        # so the with-block doesn't leak its mesh into later code.
-        @contextlib.contextmanager
-        def _restoring():
-            try:
-                yield mesh
-            finally:
-                try:
-                    sm(prev)
-                except Exception:
-                    pass
-        return _restoring()
-    return mesh  # jax <= 0.4.x: Mesh is itself a context manager
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, **kwargs):
-    """Version-compat ``jax.shard_map``.
-
-    Newer JAX promotes shard_map to the top level with a ``check_vma``
-    flag; older releases ship it as ``jax.experimental.shard_map`` with the
-    flag spelled ``check_rep``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 # stacked containers whose leaves carry a leading layer/group dim
 _STACKED = ("groups", "encoder", "decoder")

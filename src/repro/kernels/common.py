@@ -2,17 +2,16 @@
 
 Every kernel family resolves the same three questions when its routing
 factory builds an executable step — which backend mode to run in, whether
-the resident operands fit VMEM, and whether the chain is all-f32.  The
-streamfuse answers (repro/kernels/streamfuse/ops.py) are the reference
-semantics; this module is their shared home so the flashattn/rglru/ssd
-pattern modules don't each re-derive them.
+the resident operands fit VMEM, and whether the chain is all-f32.  This
+module is the one place those answers live, for the streamfuse,
+flashattn, rglru and ssd pattern modules alike.
 
 Modes:
 
-* ``"pallas"``    — compiled Pallas kernel (TPU hosts);
+* ``"pallas"``    — compiled Pallas kernel (TPU hosts, always);
 * ``"interpret"`` — the Pallas kernel body in interpret mode, forced by
-  ``CODO_PALLAS_INTERPRET=1`` (how CI exercises the true kernel path on
-  CPU runners);
+  ``CODO_PALLAS_INTERPRET=1`` on non-TPU hosts (how CI exercises the true
+  kernel path on CPU runners);
 * ``"reference"`` — the kernel's fused jnp reference under one jit
   (CPU/GPU hosts): the same fusion decision, carried by XLA.
 """
@@ -29,7 +28,10 @@ VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 
 def kernel_mode() -> str:
-    """'pallas' (compiled, TPU), 'interpret' (forced), or 'reference'."""
+    """'pallas' (compiled, TPU), 'interpret' (forced), or 'reference'.
+    On a TPU backend the answer is always 'pallas':
+    :func:`~repro.core.routing.pallas_interpret_forced` ignores the
+    interpret flag there."""
     if pallas_interpret_forced():
         return "interpret"
     import jax

@@ -28,6 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 contractions at full f32 precision on the MXU (not one bf16 pass).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -47,6 +49,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     v = v_ref[0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=HIGHEST,
                             preferred_element_type=jnp.float32) * scale
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -64,7 +67,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
 
@@ -77,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd).  Returns (B, Hq, Sq, hd)."""
     B, Hq, Sq, hd = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]

@@ -11,12 +11,9 @@ from functools import partial
 
 import jax
 
+from ..common import kernel_mode
 from .flashattn import flash_attention
 from .ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
@@ -29,4 +26,4 @@ def flash_attn(q, k, v, *, causal: bool = True, window: int = 0,
         return attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
-                           interpret=not _on_tpu())
+                           interpret=kernel_mode() != "pallas")

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc, *, nk: int):
     ki = pl.program_id(2)
@@ -29,7 +31,8 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc, *, nk: int):
 
     acc[...] += jax.lax.dot_general(
         a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _emit():
@@ -38,7 +41,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc, *, nk: int):
 
 def matmul(a: jax.Array, b: jax.Array, *, block_m: int = 128,
            block_n: int = 128, block_k: int = 128,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool = False) -> jax.Array:
     M, K = a.shape
     K2, N = b.shape
     assert K == K2

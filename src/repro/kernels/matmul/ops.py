@@ -6,12 +6,9 @@ from functools import partial
 
 import jax
 
+from ..common import kernel_mode
 from .matmul import matmul
 from .ref import matmul_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -21,4 +18,4 @@ def mm(a, b, *, block_m: int = 128, block_n: int = 128, block_k: int = 128,
     if not use_kernel:
         return matmul_ref(a, b)
     return matmul(a, b, block_m=block_m, block_n=block_n, block_k=block_k,
-                  interpret=not _on_tpu())
+                  interpret=kernel_mode() != "pallas")

@@ -40,7 +40,7 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int):
 
 
 def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool = False) -> jax.Array:
     """a, b: (B, S, D) -> h: (B, S, D) with h_t = a_t·h_{t-1} + b_t."""
     B, S, D = a.shape
     chunk = min(chunk, S)

@@ -6,16 +6,13 @@ from functools import partial
 
 import jax
 
+from ..common import kernel_mode
 from .ref import ssd_chunk_scan_ref
 from .ssd import ssd_chunk_scan
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("use_kernel",))
 def ssd_states(states, decay, *, use_kernel: bool = True):
     if not use_kernel:
         return ssd_chunk_scan_ref(states, decay)
-    return ssd_chunk_scan(states, decay, interpret=not _on_tpu())
+    return ssd_chunk_scan(states, decay, interpret=kernel_mode() != "pallas")

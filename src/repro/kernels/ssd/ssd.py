@@ -38,7 +38,7 @@ def _ssd_kernel(states_ref, decay_ref, prev_ref, h_scr):
 
 
 def ssd_chunk_scan(states: jax.Array, decay: jax.Array, *,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """states: (nc, BH, P, N); decay: (nc, BH, 1, 1).
     Returns h_prev: (nc, BH, P, N) — the state carried *into* each chunk."""
     nc, BH, P, N = states.shape

@@ -32,6 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 contractions at full f32 precision on the MXU (not one bf16 pass).
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _block(dim: int, pref: int = 128) -> int:
@@ -48,18 +50,20 @@ def _block(dim: int, pref: int = 128) -> int:
 def _chain_kernel(a_ref, w1_ref, w2_ref, o_ref, *, ew: Callable):
     h = jax.lax.dot_general(
         a_ref[...].astype(jnp.float32), w1_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     h = ew(h)                                    # fused elementwise tail(s)
     o_ref[...] = jax.lax.dot_general(
         h, w2_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
+        precision=HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def fused_matmul_chain(a: jax.Array, w1: jax.Array, w2: jax.Array, *,
                        ew: Callable | Sequence[Callable] = (),
                        block_m: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """``ew(a @ w1) @ w2`` as one Pallas kernel; ``ew`` is a single
     f32-block→f32-block callable or a sequence applied in order (empty =
     bare matmul chain)."""
@@ -100,13 +104,14 @@ def _grad_chain_kernel(*refs, ew: Callable):
     extras = [r[...].astype(jnp.float32) for r in refs[2:-1]]
     h = jax.lax.dot_general(
         a_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     o_ref[...] = ew(h, *extras).astype(o_ref.dtype)
 
 
 def fused_matmul_grad(a: jax.Array, w: jax.Array, *extras: jax.Array,
                       ew: Callable, block_m: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """``ew(a @ w, *extras)`` as one Pallas kernel — the backward-pass
     sibling of :func:`fused_matmul_chain`: a cotangent matmul whose
     gradient epilogue (``relu_grad``/``gelu_grad``/``softmax_grad`` plus
@@ -159,7 +164,8 @@ def _softmax_mm_kernel(s_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
@@ -170,7 +176,7 @@ def _softmax_mm_kernel(s_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def fused_softmax_matmul(s: jax.Array, v: jax.Array, *,
                          block_m: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool = False) -> jax.Array:
     """``softmax(s, axis=-1) @ v`` as one streaming Pallas kernel.
     ``s``: (M, K); ``v``: (K, N).  The K axis iterates on the sequential
     last grid dimension, so the softmax normalizer is the online

@@ -29,11 +29,9 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
-
 from ...core.ops import op_impl
-from ...core.routing import (KernelPattern, pallas_interpret_forced,
-                             register_kernel_pattern)
+from ...core.routing import KernelPattern, register_kernel_pattern
+from ..common import all_f32, kernel_mode, vmem_ok
 from .ref import (matmul_chain_ref, matmul_grad_ref, pad_conv_relu_ref,
                   softmax_matmul_ref)
 
@@ -45,26 +43,6 @@ EW_KINDS = frozenset({"relu", "gelu", "scale", "affine", "divc", "rdivc",
 # Gradient-epilogue kinds (backward chains): chain value first operand,
 # residual operands stream alongside it with the same row-blocking.
 GRAD_EW_KINDS = frozenset({"relu_grad", "gelu_grad", "softmax_grad"})
-
-# Resident-operand budget for compiled (TPU) kernels; interpret/reference
-# modes are unconstrained.
-VMEM_BUDGET_BYTES = 12 * 2 ** 20
-
-
-def _mode() -> str:
-    """'pallas' (compiled, TPU), 'interpret' (forced), or 'reference'."""
-    if pallas_interpret_forced():
-        return "interpret"
-    import jax
-    return "pallas" if jax.default_backend() == "tpu" else "reference"
-
-
-def _vmem_ok(*shapes) -> bool:
-    return sum(int(np.prod(s)) for s in shapes) * 4 <= VMEM_BUDGET_BYTES
-
-
-def _f32(graph, *bufs) -> bool:
-    return all(np.dtype(graph.buffers[b].dtype) == np.float32 for b in bufs)
 
 
 # --------------------------------------------------------------------------
@@ -82,7 +60,7 @@ def pad_conv_relu(x, w, *, use_kernel: bool = True):
     """relu(conv2d(pad(x), w)), stride 1, SAME; backend-dispatched."""
     if not use_kernel:
         return _ref_conv_jit()(x, w)
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "reference":
         return _ref_conv_jit()(x, w)
     from .streamfuse import fused_pad_conv_relu
@@ -112,7 +90,7 @@ def _conv_feasible(graph, tasks) -> bool:
         return False
     if int(pad_t.spec.attrs.get("pad", -1)) != k // 2:     # SAME only
         return False
-    return _f32(graph, x_buf, w_buf, relu_t.spec.outs[0])
+    return all_f32(graph, x_buf, w_buf, relu_t.spec.outs[0])
 
 
 def _conv_factory(graph, group, tasks, tile=None):
@@ -122,7 +100,7 @@ def _conv_factory(graph, group, tasks, tile=None):
     x_buf, w_buf = pad_t.spec.ins[0], conv_t.spec.ins[1]
     out_buf = relu_t.spec.outs[0]
 
-    mode = _mode()                 # resolved once; the lowering memo key
+    mode = kernel_mode()           # resolved once; the lowering memo key
     if mode == "reference":        # covers the switches that change it
         fn = _ref_conv_jit()
     else:
@@ -157,7 +135,7 @@ def _mm_chain_feasible(graph, tasks) -> bool:
     bufs = (*first.spec.ins, last.spec.ins[1], last.spec.outs[0])
     if any(len(graph.buffers[b].shape) != 2 for b in bufs[:3]):
         return False
-    return _f32(graph, *bufs)
+    return all_f32(graph, *bufs)
 
 
 def _ew_applier(ew_tasks):
@@ -175,7 +153,7 @@ def _mm_chain_tiles(graph, tasks):
     """Autotune candidates: row-block sizes for the streamed activation
     (``None`` = the kernel's 128 default).  Reference mode has no blocking
     to sweep — routed-vs-generic is the only question there."""
-    if _mode() == "reference":
+    if kernel_mode() == "reference":
         return [None]
     m = graph.buffers[tasks[0].spec.ins[0]].shape[0]
     return [None] + [{"block_m": b} for b in (64, 256)
@@ -192,9 +170,9 @@ def _mm_chain_factory(graph, group, tasks, tile=None):
     out_buf = last.spec.outs[0]
     ew = _ew_applier(tasks[1:-1])
 
-    mode = _mode()
-    if mode == "pallas" and not _vmem_ok(graph.buffers[w1_buf].shape,
-                                         graph.buffers[w2_buf].shape):
+    mode = kernel_mode()
+    if mode == "pallas" and not vmem_ok(graph.buffers[w1_buf].shape,
+                                        graph.buffers[w2_buf].shape):
         return None                     # resident weights exceed VMEM
     if mode == "reference":
         fn = jax.jit(lambda a, w1, w2: matmul_chain_ref(a, w1, w2, ew))
@@ -240,7 +218,7 @@ def _mm_grad_feasible(graph, tasks) -> bool:
         if not t.spec.ins or t.spec.ins[0] != prev:
             return False
         for b in t.spec.ins[1:]:        # residual operands ride the stream
-            if graph.buffers[b].shape != mn or not _f32(graph, b):
+            if graph.buffers[b].shape != mn or not all_f32(graph, b):
                 return False
         if kind == "softmax_grad" and int(
                 t.spec.attrs.get("axis", -1)) not in (-1, 1):
@@ -248,7 +226,7 @@ def _mm_grad_feasible(graph, tasks) -> bool:
         prev = t.spec.outs[0]
     if not has_grad:
         return False
-    return _f32(graph, a_buf, w_buf, tail[-1].spec.outs[0])
+    return all_f32(graph, a_buf, w_buf, tail[-1].spec.outs[0])
 
 
 def _grad_ew_applier(tail_tasks):
@@ -270,7 +248,7 @@ def _grad_ew_applier(tail_tasks):
 
 
 def _mm_grad_tiles(graph, tasks):
-    if _mode() == "reference":
+    if kernel_mode() == "reference":
         return [None]
     m = graph.buffers[tasks[0].spec.ins[0]].shape[0]
     return [None] + [{"block_m": b} for b in (64, 256)
@@ -286,8 +264,8 @@ def _mm_grad_factory(graph, group, tasks, tile=None):
     out_buf = tail[-1].spec.outs[0]
     ew, extra_bufs = _grad_ew_applier(tail)
 
-    mode = _mode()
-    if mode == "pallas" and not _vmem_ok(graph.buffers[w_buf].shape):
+    mode = kernel_mode()
+    if mode == "pallas" and not vmem_ok(graph.buffers[w_buf].shape):
         return None                     # resident operand exceeds VMEM
     if mode == "reference":
         fn = jax.jit(lambda a, w, *ex: matmul_grad_ref(a, w, ex, ew))
@@ -324,13 +302,13 @@ def _softmax_mm_feasible(graph, tasks) -> bool:
     v_buf = mm.spec.ins[1]
     if len(graph.buffers[v_buf].shape) != 2:
         return False
-    return _f32(graph, sm.spec.ins[0], v_buf, mm.spec.outs[0])
+    return all_f32(graph, sm.spec.ins[0], v_buf, mm.spec.outs[0])
 
 
 def _softmax_mm_tiles(graph, tasks):
     """Autotune candidates: (row, contraction) block pairs for the online
     softmax·V stream (``None`` = the kernel's 128/128 default)."""
-    if _mode() == "reference":
+    if kernel_mode() == "reference":
         return [None]
     s, k = graph.buffers[tasks[0].spec.ins[0]].shape
     out = [None]
@@ -347,8 +325,8 @@ def _softmax_mm_factory(graph, group, tasks, tile=None):
     sm, mm = tasks
     s_buf, v_buf, out_buf = sm.spec.ins[0], mm.spec.ins[1], mm.spec.outs[0]
 
-    mode = _mode()
-    if mode == "pallas" and not _vmem_ok(graph.buffers[v_buf].shape):
+    mode = kernel_mode()
+    if mode == "pallas" and not vmem_ok(graph.buffers[v_buf].shape):
         return None
     if mode == "reference":
         fn = jax.jit(softmax_matmul_ref)

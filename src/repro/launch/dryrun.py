@@ -36,7 +36,7 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.models import batch_spec, transformer as tf
 from repro.distributed.sharding import (as_shardings, batch_specs,
-                                        cache_specs, param_specs, use_mesh)
+                                        cache_specs, param_specs)
 from repro.training.train_loop import build_train_step
 from repro.training.optimizer import OptConfig
 from repro.serving.generator import build_prefill_step, build_serve_step
@@ -181,7 +181,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str) -> dict:
     try:
         fn, args = build_cell(arch_name, shape_name, mesh)
         t0 = time.time()
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(*args)
         t1 = time.time()
         compiled = lowered.compile()

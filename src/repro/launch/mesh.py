@@ -56,9 +56,9 @@ def mesh_from_spec(spec) -> Mesh:
         raise RuntimeError(
             f"sharding plan needs {spec.size} devices for mesh "
             + "x".join(f"{n}:{s}" for n, s in spec.axes)
-            + f", have {len(devs)} — run under XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={spec.size} or on "
-            f"real hardware")
+            + f", have {len(devs)} {devs[0].platform} device(s) — run on "
+            f"a host with {spec.size} chips, or for a CPU rehearsal under "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={spec.size}")
     shape = tuple(s for _, s in spec.axes)
     arr = np.asarray(devs[:spec.size]).reshape(shape)
     return Mesh(arr, spec.names)

@@ -25,4 +25,6 @@ __all__ = ["InputError", "load_input_env", "main", "serve_artifact",
 
 
 if __name__ == "__main__":
+    from repro.core.cache import enable_jax_cache
+    enable_jax_cache()
     raise SystemExit(main())

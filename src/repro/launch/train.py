@@ -23,4 +23,6 @@ __all__ = ["main"]
 
 
 if __name__ == "__main__":
+    from repro.core.cache import enable_jax_cache
+    enable_jax_cache()
     raise SystemExit(main())

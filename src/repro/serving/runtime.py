@@ -18,6 +18,8 @@ millions-of-users story (ROADMAP item 2).  A :class:`ServingRuntime` owns
 * an optional **process worker pool** (``CODO_SERVE_WORKERS``, spawn
   start method — serving workers execute jax, so fork is not safe here
   the way it is for the compile-only pool in ``core/compiler.py``).
+  Host backends only: a TPU belongs to one process, so the runtime
+  refuses ``workers > 0`` there and serves in-process.
   Workers share the disk compile cache and the ``TuningDB`` sidecar via
   environment passed at spawn; a crashed worker breaks the pool, the
   runtime **respawns** it and retries the affected requests (bounded by
@@ -197,6 +199,13 @@ class ServingRuntime:
     def __init__(self, config: ServeConfig | None = None, *, cache=None):
         from repro.core.compiler import default_cache
         self.config = config or ServeConfig.from_env()
+        if self.config.workers > 0:
+            import jax
+            if jax.default_backend() == "tpu":
+                raise ServeError(
+                    f"workers={self.config.workers} on a TPU backend: a "
+                    "chip belongs to one process, so a spawned worker "
+                    "could not reach it; serve in-process (workers=0)")
         self.cache = cache if cache is not None else default_cache()
         self.stats = ServeStats()
         self._models: dict[str, _ModelHandle] = {}
@@ -436,33 +445,37 @@ class ServingRuntime:
                     initializer=_serve_worker_init, initargs=(env,))
             return self._pool
 
-    def _break_pool(self) -> None:
+    def _break_pool(self, pool: ProcessPoolExecutor) -> None:
+        """Forget a broken pool; the next dispatch spawns a fresh one.
+        No ``shutdown()``: a broken executor has already terminated its
+        workers, and this runs inside the executor's own future callbacks,
+        which hold the lock ``shutdown()`` takes."""
         with self._pool_lock:
-            if self._pool is not None:
-                self._pool.shutdown(wait=False, cancel_futures=True)
+            if self._pool is pool:
                 self._pool = None
         with self._cond:
             self.stats.respawns += 1
 
     def _dispatch_pool(self, handle: _ModelHandle,
                        batch: list[_Request]) -> None:
+        pool = self._ensure_pool()
         try:
-            fut = self._ensure_pool().submit(
+            fut = pool.submit(
                 _serve_worker_run, handle.path, handle.generation,
                 [r.env for r in batch], not handle.blockers)
         except BrokenProcessPool as e:
-            self._break_pool()
+            self._break_pool(pool)
             self._requeue(batch, e)
             return
         fut.add_done_callback(
-            lambda f, b=batch, h=handle: self._pool_done(h, b, f))
+            lambda f, b=batch, h=handle: self._pool_done(h, b, pool, f))
 
     def _pool_done(self, handle: _ModelHandle, batch: list[_Request],
-                   fut) -> None:
+                   pool: ProcessPoolExecutor, fut) -> None:
         try:
             results, batched = fut.result()
         except BrokenProcessPool as e:
-            self._break_pool()
+            self._break_pool(pool)
             self._requeue(batch, e)
             return
         except Exception as e:          # noqa: BLE001 — becomes the response
@@ -550,11 +563,12 @@ _WORKER_PROGRAMS: dict = {}
 def _serve_worker_init(env: dict) -> None:
     """Runs once in each spawned worker: point this process at the shared
     disk compile cache and tuning-DB sidecar before any codo import binds
-    its defaults."""
+    its defaults.  The worker's jax platform is whatever it inherits from
+    the parent's environment; ``ServingRuntime`` refuses workers on a TPU
+    backend."""
     for k, v in env.items():
         if v:
             os.environ[k] = v
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _maybe_fault() -> None:

@@ -171,4 +171,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.cache import enable_jax_cache
+    enable_jax_cache()
     raise SystemExit(main())

@@ -397,3 +397,19 @@ def test_compiled_dataflow_report_mentions_diagnostics():
     assert "diagnostics:" in rep and "compile time" in rep
     assert "cache hit" in dataclasses.replace(
         c.diagnostics, cache_hit=True).table()
+
+
+def test_batch_pool_never_forks_a_device_holder(monkeypatch):
+    """The parent resolves the backend once: fork only where it holds no
+    accelerator, and every worker gets the backend pinned so it never
+    asks jax (and so never initializes a device) itself."""
+    import os
+
+    from repro.core import compiler
+    monkeypatch.delenv("CODO_MP_START", raising=False)
+    assert compiler._mp_context("tpu").get_start_method() == "spawn"
+    if "fork" in compiler.multiprocessing.get_all_start_methods():
+        assert compiler._mp_context("cpu").get_start_method() == "fork"
+    monkeypatch.setenv("CODO_BACKEND", "")
+    compiler._init_batch_worker(None, False, backend="tpu")
+    assert os.environ["CODO_BACKEND"] == "tpu"

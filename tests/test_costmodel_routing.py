@@ -318,3 +318,30 @@ def test_gate_retry_remeasures_only_offenders(monkeypatch):
          for gid, kernel, _tasks in sorted(only)])
     doc = rb.remeasure_offenders(doc)
     assert len(rb.check_gate(doc)) == 1
+
+
+class _Dev:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_device_hw_chosen_by_device_kind(monkeypatch):
+    """On a TPU the gate prices the chip jax reports; a kind the cost
+    model has no numbers for raises instead of borrowing v5e's."""
+    import jax
+    from repro.core.costmodel import V5E, HwParams, device_hw
+    other = HwParams(name="modeled")
+    assert device_hw(other) is other                 # CPU: modeled target
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v5 lite")])
+    assert device_hw(other) is V5E
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v99")])
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_hw(other)
+
+
+def test_routing_backend_does_not_swallow_import_errors(monkeypatch):
+    monkeypatch.delenv("CODO_BACKEND", raising=False)
+    monkeypatch.setitem(sys.modules, "jax", None)
+    with pytest.raises(ImportError):
+        routing_backend()

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.sharding import sanitize_spec
@@ -24,16 +24,6 @@ class FakeMesh:
     def __init__(self, shape):
         self.shape = shape
         self.axis_names = tuple(shape)
-
-
-def test_use_mesh_shim_is_context_manager():
-    """The version-compat shim must be enterable on whatever JAX is
-    installed (jax.set_mesh does not exist everywhere)."""
-    from repro.distributed.sharding import use_mesh
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("data",))
-    with use_mesh(mesh):
-        x = jnp.ones((4,))
-        assert float(x.sum()) == 4.0
 
 
 def test_sanitize_spec_drops_undivisible_when_lenient():
@@ -134,7 +124,6 @@ MULTIDEV = textwrap.dedent("""
     # 1) jitted sharded train step on a 4x2 debug mesh
     from repro.launch.mesh import make_debug_mesh
     from repro.configs import get_config
-    from repro.distributed.sharding import use_mesh
     from repro.models import transformer as tf, make_batch
     from repro.training.train_loop import jit_train_step
     from repro.training.optimizer import adamw_init, OptConfig
@@ -146,7 +135,7 @@ MULTIDEV = textwrap.dedent("""
     batch = make_batch(cfg, batch=4, seq=64, kind="train")
     step = jit_train_step(cfg, mesh, params, batch,
                           OptConfig(lr=1e-3, warmup_steps=1, total_steps=4))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(3):
             params, opt, metrics = step(params, opt, batch)
     out["train_loss"] = float(metrics["loss"])
@@ -168,7 +157,6 @@ MULTIDEV = textwrap.dedent("""
 
     # 3) compressed all-reduce under shard_map matches plain mean-free sum
     from repro.distributed import compression
-    from repro.distributed.sharding import shard_map
     cmesh = make_debug_mesh((8,), ("data",))
     g_global = jax.random.normal(jax.random.PRNGKey(3), (8, 64)) * 1e-2
     def worker(g):
@@ -176,8 +164,9 @@ MULTIDEV = textwrap.dedent("""
         st = {{}}
         red, st = compression.compressed_allreduce(grads, st, ("data",))
         return red["g"][None]
-    red = jax.jit(shard_map(worker, mesh=cmesh, in_specs=P("data"),
-                            out_specs=P("data"), check_vma=False))(g_global)
+    red = jax.jit(jax.shard_map(worker, mesh=cmesh, in_specs=P("data"),
+                                out_specs=P("data"),
+                                check_vma=False))(g_global)
     want = jnp.sum(g_global, axis=0)
     err = jnp.abs(red[0] - want).max() / (jnp.abs(want).max() + 1e-9)
     out["allreduce_rel_err"] = float(err)

@@ -332,3 +332,18 @@ def test_cli_profile_prints_routing_table(tmp_path, capsys):
     assert "kernel routing" in out
     assert "gpt2_block/opt5: 1/1 groups pallas-routed" in out
     assert "streamfuse.mmchain" in out
+
+
+def test_interpret_flag_ignored_on_tpu_backend(monkeypatch):
+    """On a TPU the compiled kernel is the real path: the CI interpret
+    switch warns and has no effect there, and is honored elsewhere."""
+    import jax
+    from repro.core.routing import pallas_interpret_forced
+    from repro.kernels.common import kernel_mode
+    monkeypatch.setenv("CODO_PALLAS_INTERPRET", "1")
+    assert pallas_interpret_forced() and kernel_mode() == "interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.warns(RuntimeWarning, match="ignored on a TPU"):
+        assert not pallas_interpret_forced()
+    with pytest.warns(RuntimeWarning):
+        assert kernel_mode() == "pallas"

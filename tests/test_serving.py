@@ -391,3 +391,14 @@ def test_serve_config_reads_env_knobs(monkeypatch):
     assert ServeConfig.from_env(workers=0).workers == 0
     monkeypatch.setenv("CODO_SERVE_MAX_QUEUE", "not-a-number")
     assert ServeConfig.from_env().max_queue == 256
+
+
+def test_workers_refused_on_tpu_backend(monkeypatch):
+    """A TPU belongs to one process: a spawned worker could not reach the
+    chip the parent holds, so the runtime refuses workers there (and
+    still serves in-process)."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ServeError, match="TPU backend"):
+        ServingRuntime(ServeConfig(workers=2))
+    ServingRuntime(ServeConfig(workers=0)).close()
