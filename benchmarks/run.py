@@ -1,10 +1,10 @@
-"""Benchmark harness — one function per paper table/figure plus the
-roofline report.  Prints ``name,value,derived`` CSV and writes
-results/bench/*.csv; ``--json`` additionally collects every suite into
-one machine-readable document (what the nightly CI job uploads).
+"""Benchmark harness — one function per paper table/figure.  Prints
+``name,value,derived`` CSV and writes results/bench/*.csv; ``--json``
+additionally collects every suite into one machine-readable document (what
+the nightly CI job uploads).
 
     PYTHONPATH=src python -m benchmarks.run              # everything
-    PYTHONPATH=src python -m benchmarks.run --only table2,roofline
+    PYTHONPATH=src python -m benchmarks.run --only table2,fig10
     PYTHONPATH=src python -m benchmarks.run --json results/bench/bench.json
 """
 
@@ -18,7 +18,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from benchmarks import (roofline, routing_bench, serving_bench,  # noqa: E402
+from benchmarks import (routing_bench, serving_bench,  # noqa: E402
                         sharding_bench, tables, train_bench)
 
 OUT = Path(__file__).resolve().parents[1] / "results" / "bench"
@@ -48,29 +48,10 @@ SUITES = {
 }
 
 
-def run_roofline() -> int:
-    rows = roofline.build_table()
-    if not rows:
-        print("roofline: no dry-run results found — run "
-              "`python -m repro.launch.dryrun` first", file=sys.stderr)
-        return 0
-    OUT.mkdir(parents=True, exist_ok=True)
-    csv = [roofline.CSV_HEADER] + [r.csv() for r in rows]
-    (OUT / "roofline.csv").write_text("\n".join(csv) + "\n")
-    ok = [r for r in rows if r.status == "ok"]
-    for r in ok:
-        print(f"roofline/{r.arch}/{r.shape}/{r.mesh},"
-              f"{r.roofline_fraction:.4f},dominant={r.dominant};"
-              f"useful={r.useful_ratio:.2f};peak_GiB={r.peak_gib:.1f}")
-    n_fit = sum(1 for r in ok if r.fits_hbm)
-    print(f"roofline/summary,{len(ok)},ok_cells;fits_hbm={n_fit}/{len(ok)}")
-    return len(ok)
-
-
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="all",
-                    help=f"comma list of {sorted(SUITES)} + roofline")
+                    help=f"comma list of {sorted(SUITES)}")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="also write every suite's rows to one JSON file")
     args = ap.parse_args(argv)
@@ -96,8 +77,6 @@ def main(argv=None) -> None:
             "rows": [{"name": r.name, "value": r.value, "derived": r.derived}
                      for r in rows],
         }
-    if wanted is None or "roofline" in wanted:
-        run_roofline()
     if args.json:
         path = Path(args.json)
         path.parent.mkdir(parents=True, exist_ok=True)

@@ -47,6 +47,7 @@ import argparse
 from typing import Any, Callable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import frontend
 from repro.core.compiler import (CodoOptions, CompiledDataflow, _UNSET,
@@ -222,8 +223,12 @@ class CompiledProgram:
     def __call__(self, *arrays, jit: bool = True, **named):
         """Run the compiled design.  Returns one array per traced output
         (a bare array for single-output programs, a tuple otherwise)."""
-        out = self.lower(jit=jit)(self.make_env(*arrays, **named))
-        vals = tuple(out[n] for n in self.output_names)
+        with TraceAnnotation("codo.call"):
+            lowered = self.lower(jit=jit)
+            with TraceAnnotation("codo.make_env"):
+                env = self.make_env(*arrays, **named)
+            out = lowered(env)
+            vals = tuple(out[n] for n in self.output_names)
         return vals[0] if len(vals) == 1 else vals
 
     def verify(self, *arrays, rtol: float | None = None,
@@ -382,12 +387,14 @@ class CompiledTrainStep:
         ``(loss, grads)`` with ``grads`` keyed by parameter name."""
         g = self.graphs
         params = dict(params) if params is not None else self.init_params()
-        fenv = self.forward.make_env(*arrays, **params, **named)
-        fouts = self.forward.lower(jit=jit)(fenv)
-        benv = {g.seeds[g.loss]: np.ones((1, 1), np.float32)}
-        for r in g.residuals:
-            benv[r] = fouts[r] if r in fouts else fenv[r]
-        bouts = self.backward.lower(jit=jit)(benv)
+        with TraceAnnotation("codo.train.forward"):
+            fenv = self.forward.make_env(*arrays, **params, **named)
+            fouts = self.forward.lower(jit=jit)(fenv)
+        with TraceAnnotation("codo.train.backward"):
+            benv = {g.seeds[g.loss]: np.ones((1, 1), np.float32)}
+            for r in g.residuals:
+                benv[r] = fouts[r] if r in fouts else fenv[r]
+            bouts = self.backward.lower(jit=jit)(benv)
         grads = {w: bouts[g.grads[w]] for w in self.param_names}
         return fouts[g.loss], grads
 
@@ -395,25 +402,35 @@ class CompiledTrainStep:
              jit: bool = True, **named):
         """One full training step: forward, backward, AdamW update.
         Returns ``(new_params, new_opt_state, metrics)`` where metrics
-        carries scalar ``loss``, ``grad_norm`` and ``lr``."""
-        loss, grads = self.value_and_grad(*arrays, params=params, jit=jit,
-                                          **named)
-        uenv = {"step": np.asarray(opt_state["step"],
-                                   np.float32).reshape(1, 1)}
-        for w in self.param_names:
-            uenv[w] = params[w]
-            uenv[f"grad_{w}"] = grads[w]
-            uenv[f"m_{w}"] = opt_state["m"][w]
-            uenv[f"v_{w}"] = opt_state["v"][w]
-        uouts = self.update.lower(jit=jit)(uenv)
+        carries scalar ``loss``, ``grad_norm`` and ``lr``.
+
+        ``codo.sync`` spans mark where the host waits for the device: each
+        conversion of a device array to a host value.  The step counter is
+        read before the first dispatch, so the host never waits between
+        the phases of one step."""
+        with TraceAnnotation("codo.train.step"):
+            with TraceAnnotation("codo.sync"):
+                counter = np.asarray(opt_state["step"], np.float32).reshape(1, 1)
+            loss, grads = self.value_and_grad(*arrays, params=params,
+                                              jit=jit, **named)
+            with TraceAnnotation("codo.train.update"):
+                uenv = {"step": counter}
+                for w in self.param_names:
+                    uenv[w] = params[w]
+                    uenv[f"grad_{w}"] = grads[w]
+                    uenv[f"m_{w}"] = opt_state["m"][w]
+                    uenv[f"v_{w}"] = opt_state["v"][w]
+                uouts = self.update.lower(jit=jit)(uenv)
+            with TraceAnnotation("codo.sync"):
+                new_step = np.asarray(uouts["new_step"], np.float32)
+                metrics = {"loss": np.asarray(loss).reshape(()),
+                           "grad_norm": np.asarray(uouts["grad_norm"]
+                                                   ).reshape(()),
+                           "lr": np.asarray(uouts["lr"]).reshape(())}
         new_params = {w: uouts[f"new_{w}"] for w in self.param_names}
         new_state = {"m": {w: uouts[f"new_m_{w}"] for w in self.param_names},
                      "v": {w: uouts[f"new_v_{w}"] for w in self.param_names},
-                     "step": np.asarray(uouts["new_step"],
-                                        np.float32).reshape(()).astype(np.int32)}
-        metrics = {"loss": np.asarray(loss).reshape(()),
-                   "grad_norm": np.asarray(uouts["grad_norm"]).reshape(()),
-                   "lr": np.asarray(uouts["lr"]).reshape(())}
+                     "step": new_step.reshape(()).astype(np.int32)}
         return new_params, new_state, metrics
 
     def verify(self, *arrays, params: dict | None = None,

@@ -32,13 +32,15 @@ spec-carrying tasks, the ``const:`` tag convention for closure-built ones).
 from __future__ import annotations
 
 import os
+import re
 import threading
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .compiler import CompiledDataflow
 from .costmodel import device_hw
@@ -89,9 +91,18 @@ class LoweredProgram:
     groups: list[FusionGroup]
     fn: Callable[[dict], dict]          # jitted: env(inputs+weights) -> outputs
     materialized: list[str] = field(default_factory=list)   # HBM intermediates
+    # The jitted function's name, ``codo_<graph>``: the device trace shows
+    # the program as ``jit_<name>``, and the counters key on it.
+    name: str = "codo_program"
+
+    def __post_init__(self):
+        self.span = "codo.dispatch." + self.name.removeprefix("codo_")
 
     def __call__(self, env: dict[str, Any]) -> dict[str, Any]:
-        return self.fn(env)
+        with _COUNT_LOCK:
+            LOWER_CACHE_STATS["dispatches"][self.name] += 1
+        with TraceAnnotation(self.span):
+            return self.fn(env)
 
     @property
     def routed_groups(self) -> list[FusionGroup]:
@@ -141,7 +152,13 @@ def fusion_groups(graph: DataflowGraph, impl: dict[str, str]) -> list[FusionGrou
 # Memoized lowerings: structural key -> LoweredProgram (LRU).
 _LOWER_CACHE: OrderedDict[tuple, LoweredProgram] = OrderedDict()
 _LOWER_LOCK = threading.Lock()
-LOWER_CACHE_STATS = {"hits": 0, "misses": 0}
+_COUNT_LOCK = threading.Lock()
+# Memo hits and misses; per jitted program (keyed by its name,
+# ``codo_<graph>``), the calls (``dispatches``) and the runs of its Python
+# body (``traces``): under jit, only when JAX traces it — a compile or a
+# persistent-cache load — and on every call without jit.
+LOWER_CACHE_STATS = {"hits": 0, "misses": 0,
+                     "dispatches": Counter(), "traces": Counter()}
 
 
 def _lower_cache_size() -> int:
@@ -152,15 +169,28 @@ def clear_lower_cache() -> None:
     with _LOWER_LOCK:
         _LOWER_CACHE.clear()
         LOWER_CACHE_STATS.update(hits=0, misses=0)
+    with _COUNT_LOCK:
+        LOWER_CACHE_STATS["dispatches"].clear()
+        LOWER_CACHE_STATS["traces"].clear()
+
+
+def program_name(graph: DataflowGraph) -> str:
+    """``codo_<graph name>`` as an identifier: the name of the graph's
+    jitted program.  It enters JAX's persistent-cache key, so it depends
+    on nothing but the graph's name."""
+    return "codo_" + re.sub(r"\W", "_", graph.name)
 
 
 def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
-                 use_registered_kernels: bool) -> list[Callable[[dict], dict]]:
-    """The executable step list: routed chains become one kernel step
-    emitted at the chain's *last* task position (every external operand of
-    every matched task is in scope by then); everything else runs task by
-    task (XLA still fuses under jit)."""
-    step_at: dict[str, Callable[[dict], dict]] = {}
+                 use_registered_kernels: bool
+                 ) -> list[tuple[str, str, Callable[[dict], dict]]]:
+    """The executable step list, ``(task, scope, fn)``: routed chains
+    become one kernel step emitted at the chain's *last* task position
+    (every external operand of every matched task is in scope by then);
+    everything else runs task by task (XLA still fuses under jit).  The
+    scope, ``g<gid>.<kernel>`` for a routed chain and ``g<gid>.xla`` for
+    the rest, names the step's ops in the compiled program."""
+    step_at: dict[str, tuple[str, Callable[[dict], dict]]] = {}
     skip: set[str] = set()
     if use_registered_kernels:
         from .routing import registered_patterns
@@ -179,7 +209,7 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
                 if step is None:        # factory declined at build time
                     continue
                 built.append(route)
-                step_at[route.tasks[-1]] = step
+                step_at[route.tasks[-1]] = (route.kernel, step)
                 skip.update(route.tasks[:-1])
             if len(built) != len(g.routes):
                 for r in g.routes:
@@ -196,11 +226,12 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
             g.kernel = XLA_FUSED
             g.decision = "generic"
 
-    steps: list[tuple[str, Callable[[dict], dict]]] = []
+    steps = []
     for t in graph.toposort():
         if t.name in skip:
             continue
-        steps.append((t.name, step_at.get(t.name, t.fn)))
+        kernel, fn = step_at.get(t.name, ("xla", t.fn))
+        steps.append((t.name, f"g{t.fused_group}.{kernel}", fn))
     return steps
 
 
@@ -243,7 +274,7 @@ def _sharded_program(graph: DataflowGraph, steps, outputs: list[str],
     from repro.launch.mesh import mesh_from_spec
 
     before, after = _coll.attach(sharding.steps)
-    emitted = {name for name, _f in steps}
+    emitted = {name for name, _scope, _f in steps}
     missing = [s.task for s in sharding.steps if s.task not in emitted]
     if missing:        # _drop_sharded_routes guarantees this never fires
         raise GraphError(
@@ -255,10 +286,11 @@ def _sharded_program(graph: DataflowGraph, steps, outputs: list[str],
 
     def body(env: dict) -> dict:
         scope = dict(env)
-        for name, f in steps:
+        for name, label, f in steps:
             for s in before.get(name, ()):
                 scope[s.buffer] = fns[id(s)](scope[s.buffer])
-            scope.update(f(scope))
+            with jax.named_scope(label):
+                scope.update(f(scope))
             for s in after.get(name, ()):
                 scope[s.buffer] = fns[id(s)](scope[s.buffer])
         return {k: scope[k] for k in outputs}
@@ -314,8 +346,10 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
                 for n in g.tasks:
                     graph.task(n).fused_group = g.gid
             _record_routing(compiled, hit.groups)
+            # The memo key leaves the graph's name out: a structurally
+            # identical graph shares the program and the first one's name.
             return LoweredProgram(graph, hit.groups, hit.fn,
-                                  list(hit.materialized))
+                                  list(hit.materialized), hit.name)
     impl = compiled.buffer_plan.impl if compiled.buffer_plan else {}
     groups = fusion_groups(graph, impl)
     if use_registered_kernels:
@@ -335,16 +369,25 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
                     and b.name not in swallowed]
 
     if sharding is None:
-        def program(env: dict) -> dict:
+        def body(env: dict) -> dict:
             scope = dict(env)
-            for _name, f in steps:
-                scope.update(f(scope))
+            for _name, label, f in steps:
+                with jax.named_scope(label):
+                    scope.update(f(scope))
             return {k: scope[k] for k in outputs}
     else:
-        program = _sharded_program(graph, steps, outputs, sharding)
+        body = _sharded_program(graph, steps, outputs, sharding)
 
+    name = program_name(graph)
+
+    def program(env: dict) -> dict:
+        with _COUNT_LOCK:
+            LOWER_CACHE_STATS["traces"][name] += 1
+        return body(env)
+
+    program.__name__ = name
     fn = jax.jit(program) if jit else program
-    out = LoweredProgram(graph, groups, fn, materialized)
+    out = LoweredProgram(graph, groups, fn, materialized, name)
     _record_routing(compiled, groups)
     if memo:
         with _LOWER_LOCK:

@@ -113,5 +113,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flashattn_mha",
     )(qf, kf, vf)
     return out.reshape(B, Hq, Sq, hd)

@@ -60,4 +60,5 @@ def matmul(a: jax.Array, b: jax.Array, *, block_m: int = 128,
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="matmul",
     )(a, b)

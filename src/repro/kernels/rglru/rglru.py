@@ -57,4 +57,5 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, b)

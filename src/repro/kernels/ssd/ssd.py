@@ -53,4 +53,5 @@ def ssd_chunk_scan(states: jax.Array, decay: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((nc, BH, P, N), states.dtype),
         scratch_shapes=[pltpu.VMEM((BH, P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(states, decay)

@@ -91,6 +91,7 @@ def fused_matmul_chain(a: jax.Array, w1: jax.Array, w2: jax.Array, *,
         out_specs=pl.BlockSpec((bm, N2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N2), a.dtype),
         interpret=interpret,
+        name="streamfuse_mmchain",
     )(a, w1, w2)
 
 
@@ -137,6 +138,7 @@ def fused_matmul_grad(a: jax.Array, w: jax.Array, *extras: jax.Array,
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         interpret=interpret,
+        name="streamfuse_mmgrad",
     )(a, w, *extras)
 
 
@@ -203,4 +205,5 @@ def fused_softmax_matmul(s: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bm, N), jnp.float32),
         ],
         interpret=interpret,
+        name="streamfuse_softmaxmm",
     )(s, v)

@@ -74,5 +74,6 @@ def fused_pad_conv_relu(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, CO, W), lambda n, h: (n, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, H, CO, W), x.dtype),
         interpret=interpret,
+        name="streamfuse_conv",
     )(*([xp] * K), w.transpose(2, 3, 0, 1))
     return out.transpose(0, 2, 1, 3)

@@ -25,6 +25,14 @@ S, D = 1024, 1024
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
+def _kernel_names(text: str) -> set[str]:
+    """The names of the Mosaic kernel calls in compiled HLO text, without
+    XLA's ``.<n>`` suffix: the names a device trace shows."""
+    return {line.split("=")[0].strip().removeprefix("ROOT ").lstrip("%")
+            .rsplit(".", 1)[0]
+            for line in text.splitlines() if KERNEL in line}
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     import jax
@@ -105,7 +113,7 @@ def test_gpt2_block_routes_compile(one_chip, on_tpu):
     assert ffn_step is None
     step, shapes = steps["flashattn.mha"]
     assert step is not None and len(shapes) == 3
-    assert KERNEL in _compile(step, shapes, one_chip)
+    assert _kernel_names(_compile(step, shapes, one_chip)) == {"flashattn_mha"}
 
 
 def test_mmchain_route_compiles_where_the_guard_admits(one_chip, on_tpu):
@@ -116,7 +124,8 @@ def test_mmchain_route_compiles_where_the_guard_admits(one_chip, on_tpu):
 
     step, shapes = _routed_steps(mlp, (S, D))["streamfuse.mmchain"]
     assert step is not None
-    assert KERNEL in _compile(step, shapes, one_chip)
+    assert _kernel_names(_compile(step, shapes, one_chip)) == {
+        "streamfuse_mmchain"}
 
 
 def _relu_grad(h, r):
@@ -141,5 +150,7 @@ def test_kernel_compiles(one_chip, name, shapes):
           "rglru.scan": rglru_scan,
           "ssd.scan": ssd_chunk_scan,
           "streamfuse.conv": fused_pad_conv_relu}[name]
-    assert KERNEL in _compile(fn, shapes, one_chip)
+    kernel = {"softmaxmm": "streamfuse_softmaxmm",
+              "mmgrad": "streamfuse_mmgrad"}.get(name, name.replace(".", "_"))
+    assert _kernel_names(_compile(fn, shapes, one_chip)) == {kernel}
 
