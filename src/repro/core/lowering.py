@@ -39,6 +39,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -94,6 +95,8 @@ class LoweredProgram:
     # The jitted function's name, ``codo_<graph>``: the device trace shows
     # the program as ``jit_<name>``, and the counters key on it.
     name: str = "codo_program"
+    # Conv tasks run as patch matmuls (``g<gid>.patchconv`` steps).
+    patch_convs: int = 0
 
     def __post_init__(self):
         self.span = "codo.dispatch." + self.name.removeprefix("codo_")
@@ -111,7 +114,8 @@ class LoweredProgram:
     def summary(self) -> str:
         return (f"lowered {self.graph.name}: {len(self.groups)} fusion groups "
                 f"({sum(len(g.tasks) for g in self.groups)} tasks, "
-                f"{len(self.routed_groups)} pallas-routed), "
+                f"{len(self.routed_groups)} pallas-routed, "
+                f"{self.patch_convs} patch-matmul convs), "
                 f"{len(self.materialized)} HBM intermediates")
 
 
@@ -154,11 +158,13 @@ _LOWER_CACHE: OrderedDict[tuple, LoweredProgram] = OrderedDict()
 _LOWER_LOCK = threading.Lock()
 _COUNT_LOCK = threading.Lock()
 # Memo hits and misses; per jitted program (keyed by its name,
-# ``codo_<graph>``), the calls (``dispatches``) and the runs of its Python
+# ``codo_<graph>``), the calls (``dispatches``), the runs of its Python
 # body (``traces``): under jit, only when JAX traces it — a compile or a
-# persistent-cache load — and on every call without jit.
+# persistent-cache load — and on every call without jit; and the conv
+# tasks it runs as patch matmuls (``patch_convs``, set at lowering).
 LOWER_CACHE_STATS = {"hits": 0, "misses": 0,
-                     "dispatches": Counter(), "traces": Counter()}
+                     "dispatches": Counter(), "traces": Counter(),
+                     "patch_convs": Counter()}
 
 
 def _lower_cache_size() -> int:
@@ -172,6 +178,7 @@ def clear_lower_cache() -> None:
     with _COUNT_LOCK:
         LOWER_CACHE_STATS["dispatches"].clear()
         LOWER_CACHE_STATS["traces"].clear()
+        LOWER_CACHE_STATS["patch_convs"].clear()
 
 
 def program_name(graph: DataflowGraph) -> str:
@@ -230,9 +237,94 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
     for t in graph.toposort():
         if t.name in skip:
             continue
-        kernel, fn = step_at.get(t.name, ("xla", t.fn))
+        if t.name in step_at:
+            kernel, fn = step_at[t.name]
+        elif takes_patch_conv(graph, t):
+            kernel, fn = "patchconv", _patch_conv_step(t.spec)
+        else:
+            kernel, fn = "xla", t.fn
         steps.append((t.name, f"g{t.fused_group}.{kernel}", fn))
     return steps
+
+
+# An ungrouped convolution with few input channels leaves the 128 lanes of
+# XLA's convolution mostly empty (C_in = 3: 0.31 TFLOP/s on a v5e, against
+# ~6.4 at C_in >= 128).  Its patch matrix, C_in*kh*kw deep, fills them.
+LANES = 128
+# Most input channels a convolution may have to run as a patch matmul.  In
+# ResNet-18's program on a v5e each convolution of C_in <= 64 gains when it
+# alone is patched: the stem 740 µs a run, the two 64->64 ones 104 µs each,
+# the 64->128 3x3/2 4 µs; those of C_in >= 128 lose 274 µs together.  There
+# XLA's convolutions read C on the lanes, which 64 channels fill half (the
+# 64->64 ones, reading a routed kernel's output, take 133 µs).  Jitted
+# alone the C_in = 64 ones tie or lose, so a program that lays them out
+# otherwise may gain nothing from them (docs/architecture.md §5).
+PATCH_CONV_MAX_CIN = 64
+
+
+def patch_conv_rule(groups: int, c_in: int, kh: int, kw: int) -> bool:
+    """Whether an OIHW convolution of these shapes runs as a patch matmul:
+    ungrouped, with few input channels, and a patch at least a lane deep."""
+    return (groups == 1 and c_in <= PATCH_CONV_MAX_CIN
+            and c_in * kh * kw >= LANES)
+
+
+def takes_patch_conv(graph: DataflowGraph, t: Task) -> bool:
+    """A ``conv2d`` task (declarative, no closure override) whose weight's
+    shapes pass :func:`patch_conv_rule`."""
+    spec = t.spec
+    if spec is None or spec.kind != "conv2d" or t.fn_is_closure:
+        return False
+    _o, c_in, kh, kw = graph.buffers[spec.ins[1]].shape
+    return patch_conv_rule(int(spec.attrs.get("groups", 1)), c_in, kh, kw)
+
+
+def _phase_major(x, s: int):
+    """``x`` (NCHW) with its rows and columns reordered by their phase
+    modulo ``s``: row ``s*a + p`` moves to ``p*ceil(H/s) + a`` (zero rows
+    pad H to a multiple of ``s``), and the same for columns.  Pure data
+    movement, and no lane-strided access: a strided slice along the lanes
+    costs a v5e ~110 µs for the stem's 3x112x112 tap."""
+    n, c, h, w = x.shape
+    hp, wp = -(-h // s) * s, -(-w // s) * s
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, hp - h), (0, wp - w)))
+    x = x.reshape(n, c, hp // s, s, wp).transpose(0, 1, 3, 2, 4)
+    x = x.reshape(n, c, hp, wp).transpose(0, 1, 3, 2)       # columns on rows
+    x = x.reshape(n, c, wp // s, s, hp).transpose(0, 1, 3, 2, 4)
+    return x.reshape(n, c, wp, hp).transpose(0, 1, 3, 2)
+
+
+def patch_conv2d(x, w, stride: int):
+    """``conv2d``'s VALID NCHW x OIHW convolution as one matmul.  The patch
+    matrix holds, for tap ``(i, j)`` and output ``(oh, ow)``, the input at
+    ``(s*oh + i, s*ow + j)``: after :func:`_phase_major` every tap is a
+    contiguous window, cut as ``kw`` column windows and then ``kh`` row
+    windows of those.  It is contracted over ``(c, kh, kw)`` with the
+    weight at ``HIGHEST``; the output dimensions stay on the lanes.
+    (``lax.conv_general_dilated_patches`` is itself a convolution with C
+    input channels, and would leave the lanes as empty.)"""
+    n, c, h, wd = x.shape
+    o, _c, kh, kw = w.shape
+    s = stride
+    oh, ow = (h - kh) // s + 1, (wd - kw) // s + 1
+    if s > 1:
+        x = _phase_major(x, s)
+    hq, wq = x.shape[2] // s, x.shape[3] // s
+    cols = jnp.stack([jax.lax.slice_in_dim(x, (j % s) * wq + j // s,
+                                           (j % s) * wq + j // s + ow, axis=3)
+                      for j in range(kw)], axis=3)      # n c H kw ow
+    taps = jnp.stack([jax.lax.slice_in_dim(cols, (i % s) * hq + i // s,
+                                           (i % s) * hq + i // s + oh, axis=2)
+                      for i in range(kh)], axis=2)      # n c kh oh kw ow
+    y = jax.lax.dot_general(w, taps, (((1, 2, 3), (1, 2, 4)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST)
+    return y.transpose(1, 0, 2, 3)                      # o n oh ow -> NCHW
+
+
+def _patch_conv_step(spec) -> Callable[[dict], dict]:
+    x, w = spec.ins
+    out, stride = spec.outs[0], int(spec.attrs.get("stride", 1))
+    return lambda env: {out: patch_conv2d(env[x], env[w], stride)}
 
 
 def _drop_sharded_routes(groups: list[FusionGroup], sharding) -> None:
@@ -349,7 +441,8 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
             # The memo key leaves the graph's name out: a structurally
             # identical graph shares the program and the first one's name.
             return LoweredProgram(graph, hit.groups, hit.fn,
-                                  list(hit.materialized), hit.name)
+                                  list(hit.materialized), hit.name,
+                                  hit.patch_convs)
     impl = compiled.buffer_plan.impl if compiled.buffer_plan else {}
     groups = fusion_groups(graph, impl)
     if use_registered_kernels:
@@ -387,7 +480,10 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
 
     program.__name__ = name
     fn = jax.jit(program) if jit else program
-    out = LoweredProgram(graph, groups, fn, materialized, name)
+    patch_convs = sum(scope.endswith(".patchconv") for _n, scope, _f in steps)
+    with _COUNT_LOCK:
+        LOWER_CACHE_STATS["patch_convs"][name] = patch_convs
+    out = LoweredProgram(graph, groups, fn, materialized, name, patch_convs)
     _record_routing(compiled, groups)
     if memo:
         with _LOWER_LOCK:

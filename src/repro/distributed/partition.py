@@ -66,6 +66,9 @@ class _Prop:
         self.mesh = mesh
         self.dims: dict[str, list] = {}      # buffer -> [axis|None]*ndim
         self.events: list[dict] = []         # raw collective events
+        # The placement a graph input or weight enters the program with,
+        # kept when a gather replicates it in ``dims``.
+        self.entry: dict[str, list] = {}
 
     def spec(self, name: str) -> list:
         if name not in self.dims:
@@ -80,6 +83,8 @@ class _Prop:
     def gather(self, name: str, task: str, dims: Iterable[int] | None = None):
         """Replicate ``name`` (fully, or along ``dims``) before ``task``."""
         spec = self.spec(name)
+        if self.graph.buffers[name].kind in ("input", "weight"):
+            self.entry.setdefault(name, list(spec))
         targets = range(len(spec)) if dims is None else dims
         for d in targets:
             if spec[d] is not None:
@@ -216,7 +221,7 @@ def propagate(graph, mesh: MeshSpec, strategy: str):
         if task.spec is None:
             raise PartitionError(f"task {task.name} has no op spec")
         _visit(st, task, tp_axis)
-    specs = {name: ShardSpec(tuple(st.spec(name)))
+    specs = {name: ShardSpec(tuple(st.entry.get(name, st.spec(name))))
              for name in graph.buffers}
     return specs, st.events
 
