@@ -66,6 +66,10 @@ def opt_attrs(oc=None) -> dict:
     return {k: getattr(oc, k, v) for k, v in DEFAULT_OPT.items()}
 
 
+# Elementwise kinds whose VJP rules assume operands of the output's shape.
+_ELEMENTWISE_BINARY = frozenset({"add", "vadd", "mul", "div"})
+
+
 class _GradGB(GB):
     """GB whose generated names carry a ``d<n>_`` prefix — a namespace
     disjoint from any traced forward graph's buffers, so imported
@@ -135,8 +139,8 @@ class _BwdBuilder:
     def matmul(self, a, b):
         return self.gb.matmul(a, b)
 
-    def transpose(self, x):
-        return self.gb.transpose(x)
+    def transpose(self, x, perm=None):
+        return self.gb.transpose(x, perm)
 
     def mv(self, A, x, trans=False):
         return self.gb.mv(A, x, trans=trans)
@@ -316,6 +320,11 @@ def build_backward(src: DataflowGraph, *, wrt=None,
             rule = vjp_rule(spec.kind)
         except UnknownOpError as e:
             raise AutodiffError(f"{src.name}: task {t.name}: {e}") from None
+        if any(b.shape(i) != b.shape(spec.outs[0]) for i in spec.ins) \
+                and spec.kind in _ELEMENTWISE_BINARY:
+            raise AutodiffError(
+                f"{src.name}: task {t.name}: no VJP rule for a broadcasting "
+                f"{spec.kind!r} (its cotangent would need a reduction)")
         contrib = rule(spec, live, b)
         pairs = contrib.items() if isinstance(contrib, dict) else contrib
         for in_name, c in pairs:

@@ -88,11 +88,12 @@ def _fifo_depth(graph: DataflowGraph, buffer: str) -> int:
 
 def determine_buffers(graph: DataflowGraph) -> BufferPlan:
     plan = BufferPlan()
+    writers, readers = graph.access_index()
     for buf in graph.buffers.values():
         if buf.kind in ("input", "weight"):
             continue
-        prods = graph.producers(buf.name)
-        cons = graph.consumers(buf.name)
+        prods = writers.get(buf.name, [])
+        cons = readers.get(buf.name, [])
         if not prods or not cons:
             # graph boundary (model output): stays an off-chip stream
             if buf.kind == "intermediate":

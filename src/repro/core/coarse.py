@@ -25,10 +25,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-from .graph import Access, Buffer, DataflowGraph, Loop, Task, full_index
+from .graph import (REGION_TAG, Access, Buffer, DataflowGraph, Loop, Task,
+                    full_index)
 from .ops import OpSpec
 from .patterns import MPMC, MPSC, SPMC, coarse_violations
 
+# The fixpoint's least cap.  Each rewrite settles a buffer (a duplicator)
+# or makes a multi-producer one single-producer, so a graph needs at most
+# two rewrites a buffer, and the cap grows with its buffers.
 _MAX_ITERS = 64
 
 # Pipeline declaration consumed by passes.default_passes().
@@ -106,6 +110,8 @@ def _insert_duplicator(graph: DataflowGraph, buffer: str, report: CoarseReport) 
         spec=OpSpec("dup", (buffer,), tuple(d for (_c, d) in copies)),
     )
     node.tags.add("coarse-duplicator")
+    node.tags.update(t for p in producers[:1] for t in p.tags
+                     if t.startswith(REGION_TAG))
     graph.add_task(node)
     report.duplicators_inserted.append(node.name)
 
@@ -196,6 +202,7 @@ def _fuse_producers(graph: DataflowGraph, buffer: str, report: CoarseReport) -> 
         spec=fused_spec,
     )
     fused.tags.add("coarse-fused")
+    fused.tags.update(t for t in last.tags if t.startswith(REGION_TAG))
     if not fusable:
         # Differing inner structure / carried deps: the paper inserts extra
         # control logic; we keep the fused node but flag it so the scheduler
@@ -218,7 +225,7 @@ def _fuse_producers(graph: DataflowGraph, buffer: str, report: CoarseReport) -> 
 def eliminate_coarse(graph: DataflowGraph) -> CoarseReport:
     """Fixpoint application of Alg. 1 over all buffers."""
     report = CoarseReport()
-    for it in range(_MAX_ITERS):
+    for it in range(max(_MAX_ITERS, 2 * len(graph.buffers) + 1)):
         violations = coarse_violations(graph)
         report.iterations = it
         if not violations:

@@ -76,6 +76,20 @@ OP_II: dict[str, float] = {
 # Extra pipeline depth (fill) per op — constant, small.
 OP_DEPTH: dict[str, float] = {"softmax": 24.0, "norm": 12.0}
 
+# Per-spec-kind intervals where one op class spans kinds of different
+# cost: the transcendental elementwise kinds run on the EUP (``exp``'s
+# interval), softplus is an exp and a log, and a causal conv1d of width k
+# does k multiply-adds per element.
+KIND_II: dict[str, float] = {"exp": 4.0, "silu": 4.0, "softplus": 8.0,
+                             "conv1d_causal": 4.0}
+
+
+def task_ii(task: Task) -> float:
+    """Cycles per innermost iteration at degree 1."""
+    if task.spec is not None and task.spec.kind in KIND_II:
+        return KIND_II[task.spec.kind]
+    return OP_II.get(task.op, 1.0)
+
 
 # --------------------------------------------------------------------------
 # Per-task cost
@@ -135,7 +149,7 @@ def _offchip_read_bytes(graph: DataflowGraph, task: Task) -> dict[int, float]:
 
 
 def task_cost(graph: DataflowGraph, task: Task, hw: HwParams = V5E) -> TaskCost:
-    ii = OP_II.get(task.op, 1.0)
+    ii = task_ii(task)
     degree = task_degree(task)
     iters = task.total_iters
     compute = iters * ii / degree + OP_DEPTH.get(task.op, 0.0)
@@ -212,6 +226,7 @@ def graph_latency(graph: DataflowGraph, hw: HwParams = V5E,
                   sequential: bool = False) -> GraphCost:
     costs = {t.name: task_cost(graph, t, hw) for t in graph.tasks}
     order = graph.toposort()
+    writers, _readers = graph.access_index()
     start: dict[str, float] = {}
     finish: dict[str, float] = {}
     impl = plan.impl if plan is not None else {
@@ -221,8 +236,7 @@ def graph_latency(graph: DataflowGraph, hw: HwParams = V5E,
         c = costs[t.name]
         ready = 0.0
         for a in t.reads:
-            buf = graph.buffers[a.buffer]
-            prods = graph.producers(a.buffer)
+            prods = writers.get(a.buffer)
             if not prods:
                 continue
             p = prods[0]
@@ -243,7 +257,7 @@ def graph_latency(graph: DataflowGraph, hw: HwParams = V5E,
         # its last block/element.
         drain = 0.0
         for a in t.reads:
-            for p in graph.producers(a.buffer):
+            for p in writers.get(a.buffer, ()):
                 if impl.get(a.buffer) == FIFO:
                     tail = c.latency / max(t.total_iters, 1)
                 else:

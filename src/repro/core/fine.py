@@ -25,7 +25,7 @@ from dataclasses import dataclass, field
 
 from .graph import DataflowGraph, Task
 from .patterns import (BROADCAST_REREAD, MULTI_WRITE, ORDER_MISMATCH,
-                       access_sig, arrival_order, fine_violations,
+                       access_sig, arrival_order, fine_violations_edge,
                        index_dims, reduction_dims)
 
 _MAX_ITERS = 200
@@ -183,47 +183,83 @@ def generate_permutation(graph: DataflowGraph, reference: Task, target: Task,
 # --------------------------------------------------------------------------
 
 
+def _attempt(graph: DataflowGraph, v, report: FineReport) -> Task | None:
+    """Try the rewrite for violation ``v``; the task it changed, or None.
+    Whether it succeeds depends only on the edge's two tasks, so a failed
+    attempt fails again until one of them changes."""
+    if v.kind == MULTI_WRITE:
+        t = graph.task(v.producer)
+        if rewrite_reduction_write(t, v.buffer):
+            report.reductions_rewritten.append(f"{t.name}:{v.buffer}")
+            return t
+    elif v.kind == BROADCAST_REREAD:
+        t = graph.task(v.consumer)
+        if rewrite_reduction_read(t, v.buffer):
+            report.reductions_rewritten.append(f"{t.name}:{v.buffer}(read)")
+            return t
+    elif v.kind == ORDER_MISMATCH:
+        p, c = graph.task(v.producer), graph.task(v.consumer)
+        if "permuted" in p.tags and "permuted" in c.tags:
+            return None  # both already aligned to references; unresolvable here
+        ref, tgt = (p, c) if _intensity(p) >= _intensity(c) else (c, p)
+        if "permuted" in tgt.tags or "reuse-rewritten" in tgt.tags:
+            ref, tgt = tgt, ref   # never un-permute an aligned task
+        if "permuted" in tgt.tags or "reuse-rewritten" in tgt.tags:
+            return None
+        pm = generate_permutation(graph, ref, tgt, v.buffer)
+        if pm is not None:
+            report.permutations.append(pm)
+            return tgt
+    return None
+
+
 def eliminate_fine(graph: DataflowGraph) -> FineReport:
     """Fixpoint: rewrite reductions first (count repair), then permute loop
     orders toward the bottleneck reference (order repair).  Violations that
     survive (STENCIL_REREAD before reuse generation, genuine count
-    mismatches) are left for buffers.py to downgrade to ping-pong."""
+    mismatches) are left for buffers.py to downgrade to ping-pong.
+
+    Each iteration applies the first rewrite, in edge order, that succeeds.
+    The passes' rewrites change loop nests and accesses, never which task
+    reads or writes which buffer, so the edges are listed once; after a
+    rewrite only the edges of the task it changed are checked again, and
+    violations whose rewrite failed are skipped until then.  Every rewrite
+    is one-shot (a write or read hoisted once, a task permuted once), so
+    the cap is never below the graph's accesses and tasks: each layer of a
+    deep graph gets the rewrites it would get alone."""
     report = FineReport()
-    for it in range(_MAX_ITERS):
+    edges = graph.internal_edges()
+    by_task: dict[str, list[int]] = {}
+    for i, (p, _b, c) in enumerate(edges):
+        by_task.setdefault(p.name, []).append(i)
+        if c.name != p.name:
+            by_task.setdefault(c.name, []).append(i)
+    # Per edge: its violations, each with a flag "its rewrite failed".
+    found = [[[v, False] for v in fine_violations_edge(graph, p, b, c)]
+             for p, b, c in edges]
+    cap = max(_MAX_ITERS, sum(len(t.reads) + len(t.writes) + 1
+                              for t in graph.tasks) + 1)
+    for it in range(cap):
         report.iterations = it
-        vs = fine_violations(graph)
-        if not vs:
+        if not any(found):
             break
-        progressed = False
-        for v in vs:
-            if v.kind == MULTI_WRITE:
-                t = graph.task(v.producer)
-                if rewrite_reduction_write(t, v.buffer):
-                    report.reductions_rewritten.append(f"{t.name}:{v.buffer}")
-                    progressed = True
-                    break
-            elif v.kind == BROADCAST_REREAD:
-                t = graph.task(v.consumer)
-                if rewrite_reduction_read(t, v.buffer):
-                    report.reductions_rewritten.append(f"{t.name}:{v.buffer}(read)")
-                    progressed = True
-                    break
-            elif v.kind == ORDER_MISMATCH:
-                p, c = graph.task(v.producer), graph.task(v.consumer)
-                if "permuted" in p.tags and "permuted" in c.tags:
-                    continue  # both already aligned to references; unresolvable here
-                ref, tgt = (p, c) if _intensity(p) >= _intensity(c) else (c, p)
-                if "permuted" in tgt.tags or "reuse-rewritten" in tgt.tags:
-                    ref, tgt = tgt, ref   # never un-permute an aligned task
-                if "permuted" in tgt.tags or "reuse-rewritten" in tgt.tags:
+        changed = None
+        for per_edge in found:
+            for entry in per_edge:
+                if entry[1]:
                     continue
-                pm = generate_permutation(graph, ref, tgt, v.buffer)
-                if pm is not None:
-                    report.permutations.append(pm)
-                    progressed = True
+                changed = _attempt(graph, entry[0], report)
+                if changed is None:
+                    entry[1] = True
+                else:
                     break
-        if not progressed:
+            if changed is not None:
+                break
+        if changed is None:
             break
+        for i in by_task.get(changed.name, ()):
+            p, b, c = edges[i]
+            found[i] = [[v, False] for v in fine_violations_edge(graph, p, b, c)]
     report.unresolved = [f"{v.kind}:{v.buffer}({v.producer}->{v.consumer})"
-                         for v in fine_violations(graph)]
+                         for per_edge in found for v, _failed in per_edge]
     return report

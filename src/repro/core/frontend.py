@@ -41,16 +41,19 @@ execution materializes registry implementations, which import jax lazily.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import inspect
+import re
 import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
 import numpy as np
 
-from .graph import (Access, Buffer, DataflowGraph, Loop, Task, conv2d_task,
-                    ewise_task, full_index, idx, matmul_task, pad_task,
-                    pool_task)
+from .graph import (REGION_TAG, Access, Buffer, DataflowGraph, Loop, Task,
+                    conv2d_task, ewise_task, full_index, idx, matmul_task,
+                    pad_task, pool_task, task_region)
 from .ops import OpSpec, materialize
 
 
@@ -187,14 +190,31 @@ class GB:
             spec=OpSpec("gelu", (x,), (out,))))
         return out
 
-    def add(self, a: str, b: str) -> str:
-        shp = self.shape[a]
-        out = self.buf(self.fresh("add"), shp)
-        dims = ["n", "c", "h", "w"][:len(shp)] if len(shp) == 4 else None
-        self.g.add_task(ewise_task(
-            self.fresh("add_t"), out, [a, b], shp, op="ewise",
-            spec=OpSpec("add", (a, b), (out,)), dim_names=dims))
+    def _binary(self, kind: str, a: str, b: str, prefix: str,
+                attrs: dict | None = None, flops: float = 1.0,
+                nchw: bool = False) -> str:
+        """Elementwise ``kind`` of two operands broadcast to a common shape
+        (numpy's rules); a broadcast operand reads its size-1 dims at a
+        constant index and leaves out missing leading ones."""
+        sa, sb = self.shape[a], self.shape[b]
+        try:
+            shp = tuple(int(s) for s in np.broadcast_shapes(sa, sb))
+        except ValueError:
+            raise TraceError(f"{kind}: operand shapes {sa} and {sb} do not "
+                             f"broadcast") from None
+        out = self.buf(self.fresh(prefix), shp)
+        dims = (["n", "c", "h", "w"] if nchw and len(shp) == 4
+                else [f"i{k}" for k in range(len(shp))])
+        t = ewise_task(self.fresh(f"{prefix}_t"), out, [a, b], shp,
+                       op="ewise", flops_per_iter=flops, dim_names=dims,
+                       spec=OpSpec(kind, (a, b), (out,), attrs or {}))
+        t.reads = [Access(x, _broadcast_index(self.shape[x], shp, dims),
+                          False) for x in (a, b)]
+        self.g.add_task(t)
         return out
+
+    def add(self, a: str, b: str) -> str:
+        return self._binary("add", a, b, "add", nchw=True)
 
     def maxpool(self, x: str, k: int) -> str:
         n, c, h, w = self.shape[x]
@@ -264,22 +284,23 @@ class GB:
         return out
 
     def matmul(self, a: str, b: str) -> str:
-        """2-D ``(M, K) @ (K, N)`` or batched 3-D ``(B, M, K) @ (B, K, N)``
-        (one leading batch dim; the batch loop prefixes all three
+        """2-D ``(M, K) @ (K, N)`` or batched ``(..., M, K) @ (..., K, N)``
+        over equal leading batch dims (the batch loops prefix all three
         accesses)."""
         sa, sb = self.shape[a], self.shape[b]
-        if len(sa) == 3 or len(sb) == 3:
-            if len(sa) != 3 or len(sb) != 3:
+        if len(sa) >= 3 or len(sb) >= 3:
+            if len(sa) != len(sb):
                 raise TraceError(
-                    f"batched matmul needs two 3-D operands (got {sa} and "
-                    f"{sb}); lift the 2-D side explicitly")
-            bt, m, k = sa
-            bt2, k2, n = sb
+                    f"batched matmul needs operands of one rank (got {sa} "
+                    f"and {sb}); lift the lower-rank side explicitly")
+            *bt, m, k = sa
+            *bt2, k2, n = sb
             if bt != bt2 or k != k2:
                 raise TraceError(f"batched matmul shape mismatch: {sa} @ {sb}")
-            out = self.buf(self.fresh("bmm"), (bt, m, n))
+            out = self.buf(self.fresh("bmm"), (*bt, m, n))
             self.g.add_task(matmul_task(
-                self.fresh("bmm_t"), out, a, b, m, n, k, batch=bt,
+                self.fresh("bmm_t"), out, a, b, m, n, k,
+                batch=bt[0] if len(bt) == 1 else tuple(bt),
                 spec=OpSpec("matmul", (a, b), (out,))))
             return out
         m, k = sa
@@ -291,10 +312,28 @@ class GB:
             spec=OpSpec("matmul", (a, b), (out,))))
         return out
 
-    def transpose(self, x: str) -> str:
-        """2-D transpose, or a last-two-dims swap for 3-D (batched)
-        operands (spec attr ``perm=(0, 2, 1)``)."""
+    def transpose(self, x: str, perm: Sequence[int] | None = None) -> str:
+        """2-D transpose, or a last-two-dims swap for batched operands
+        (spec attr ``perm=(0, 2, 1)`` at rank 3); with ``perm``, any axis
+        permutation: result dim ``j`` is operand dim ``perm[j]``."""
         shp = self.shape[x]
+        if perm is None and len(shp) > 3:
+            perm = (*range(len(shp) - 2), len(shp) - 1, len(shp) - 2)
+        if perm is not None:
+            perm = tuple(int(p) % len(shp) for p in perm)
+            if sorted(perm) != list(range(len(shp))):
+                raise TraceError(f"transpose perm {perm} is not a "
+                                 f"permutation of the axes of {shp}")
+            dims = [f"i{k}" for k in range(len(shp))]
+            out = self.buf(self.fresh("tr"), tuple(shp[p] for p in perm))
+            self.g.add_task(Task(
+                self.fresh("transpose_t"),
+                loops=[Loop(d, int(n)) for d, n in zip(dims, shp)],
+                reads=[Access(x, full_index(dims), False)],
+                writes=[Access(out, tuple(idx(dims[p]) for p in perm), True)],
+                op="copy", flops_per_iter=0.0,
+                spec=OpSpec("transpose", (x,), (out,), {"perm": perm})))
+            return out
         if len(shp) == 3:
             bt, m, n = shp
             out = self.buf(self.fresh("tr"), (bt, n, m))
@@ -367,14 +406,9 @@ class GB:
         return out
 
     def vadd(self, a: str, b: str, alpha: float = 1.0, beta: float = 1.0) -> str:
-        shp = self.shape[a]
-        out = self.buf(self.fresh("vadd"), shp)
         # alpha/beta are structural via OpSpec.attrs (see scale()).
-        self.g.add_task(ewise_task(
-            self.fresh("vadd_t"), out, [a, b], shp, op="ewise",
-            spec=OpSpec("vadd", (a, b), (out,),
-                        {"alpha": float(alpha), "beta": float(beta)})))
-        return out
+        return self._binary("vadd", a, b, "vadd",
+                            {"alpha": float(alpha), "beta": float(beta)})
 
     def affine(self, x: str, a: float, b: float) -> str:
         """``a*x + b`` — scalar-operand add/sub (reflected-operator sugar)."""
@@ -403,20 +437,10 @@ class GB:
         return out
 
     def div(self, a: str, b: str) -> str:
-        shp = self.shape[a]
-        out = self.buf(self.fresh("div"), shp)
-        self.g.add_task(ewise_task(
-            self.fresh("div_t"), out, [a, b], shp, op="ewise", flops_per_iter=4.0,
-            spec=OpSpec("div", (a, b), (out,))))
-        return out
+        return self._binary("div", a, b, "div", flops=4.0)
 
     def mul(self, a: str, b: str) -> str:
-        shp = self.shape[a]
-        out = self.buf(self.fresh("mul"), shp)
-        self.g.add_task(ewise_task(
-            self.fresh("mul_t"), out, [a, b], shp, op="ewise",
-            spec=OpSpec("mul", (a, b), (out,))))
-        return out
+        return self._binary("mul", a, b, "mul")
 
     def const(self, value) -> str:
         """A compile-time constant array as a producer task (array-left
@@ -516,6 +540,137 @@ class GB:
                  spec=OpSpec("slice", (x,), (out,),
                              {"starts": starts, "sizes": sizes}))
         self.g.add_task(t)
+        return out
+
+    def reshape(self, x: str, shape: Sequence[int]) -> str:
+        """Row-major reshape.  The loop nest runs over the common
+        refinement of both shapes (each dim of either is a run of
+        consecutive factors), so both accesses stay affine; shapes with
+        no such refinement are refused."""
+        src = self.shape[x]
+        shape = tuple(int(s) for s in shape)
+        if int(np.prod(src)) != int(np.prod(shape)):
+            raise TraceError(f"cannot reshape {src} to {shape}")
+        sizes: dict[str, int] = {}
+        groups = ([[] for _ in src], [[] for _ in shape])
+        i = j = -1
+        ri = rj = 1
+        while True:
+            while ri == 1 and i + 1 < len(src):
+                i, ri = i + 1, src[i + 1]
+            while rj == 1 and j + 1 < len(shape):
+                j, rj = j + 1, shape[j + 1]
+            if ri == 1 or rj == 1:
+                break
+            f = min(ri, rj)
+            if max(ri, rj) % f:
+                raise TraceError(f"reshape {src} -> {shape} regroups dims "
+                                 "across boundaries (no affine loop nest)")
+            var = f"f{len(sizes)}"
+            sizes[var] = f
+            groups[0][i].append(var)
+            groups[1][j].append(var)
+            ri, rj = ri // f, rj // f
+
+        def index(side):
+            out = []
+            for g in side:
+                terms, stride = [], 1
+                for var in reversed(g):
+                    terms.append((var, stride))
+                    stride *= sizes[var]
+                out.append(idx(*reversed(terms)))
+            return tuple(out)
+
+        out = self.buf(self.fresh("rs"), shape)
+        self.g.add_task(Task(
+            self.fresh("reshape_t"),
+            loops=[Loop(v, f) for v, f in sizes.items()],
+            reads=[Access(x, index(groups[0]), False)],
+            writes=[Access(out, index(groups[1]), True)],
+            op="copy", flops_per_iter=0.0,
+            spec=OpSpec("reshape", (x,), (out,), {"shape": shape})))
+        return out
+
+    def broadcast_to(self, x: str, shape: Sequence[int]) -> str:
+        """``x`` broadcast to ``shape`` (numpy's rules)."""
+        shape = tuple(int(s) for s in shape)
+        if tuple(np.broadcast_shapes(self.shape[x], shape)) != shape:
+            raise TraceError(f"cannot broadcast {self.shape[x]} to {shape}")
+        out = self.buf(self.fresh("bc"), shape)
+        dims = [f"i{k}" for k in range(len(shape))]
+        self.g.add_task(Task(
+            self.fresh("bcast_t"),
+            loops=[Loop(d, int(n)) for d, n in zip(dims, shape)],
+            reads=[Access(x, _broadcast_index(self.shape[x], shape, dims),
+                          False)],
+            writes=[Access(out, full_index(dims), True)],
+            op="copy", flops_per_iter=0.0,
+            spec=OpSpec("bcast", (x,), (out,), {"shape": shape})))
+        return out
+
+    # ---- sequence-model ops ------------------------------------------------
+
+    def param(self, shape: Sequence[int]) -> str:
+        """A weight buffer with no producing task (a bias, a norm's scale,
+        a per-head constant)."""
+        return self.weight(self.fresh("wp"), tuple(int(s) for s in shape))
+
+    def unary(self, kind: str, x: str, flops: float = 1.0,
+              attrs: dict | None = None) -> str:
+        """One-operand elementwise ``kind`` (silu, softplus, exp, the causal
+        mask, a cumulative sum) over the operand's shape."""
+        shp = self.shape[x]
+        out = self.buf(self.fresh(kind), shp)
+        self.g.add_task(ewise_task(
+            self.fresh(f"{kind}_t"), out, [x], shp, op="ewise",
+            flops_per_iter=flops, spec=OpSpec(kind, (x,), (out,), attrs or {})))
+        return out
+
+    def rmsnorm(self, x: str, eps: float) -> str:
+        """RMSNorm over the last axis with a learned scale of that width."""
+        shp = self.shape[x]
+        w = self.param((shp[-1],))
+        out = self.buf(self.fresh("rms"), shp)
+        dims = [f"i{k}" for k in range(len(shp))]
+        t = ewise_task(self.fresh("rmsnorm_t"), out, [x, w], shp, op="norm",
+                       flops_per_iter=4.0, dim_names=dims,
+                       spec=OpSpec("rmsnorm", (x, w), (out,),
+                                   {"eps": float(eps)}))
+        t.reads[1] = Access(w, (idx(dims[-1]),), False)
+        self.g.add_task(t)
+        return out
+
+    def conv1d_causal(self, x: str, k: int) -> str:
+        """Depthwise causal convolution of width ``k`` with a bias over the
+        rows of ``x (S, C)``; taps stored ``(k, C)``."""
+        s, c = self.shape[x]
+        w, b = self.param((k, c)), self.param((c,))
+        out = self.buf(self.fresh("conv1d"), (s, c))
+        t = ewise_task(self.fresh("conv1d_t"), out, [x, w, b], (s, c),
+                       op="ewise", flops_per_iter=2.0 * k,
+                       spec=OpSpec("conv1d_causal", (x, w, b), (out,)))
+        t.reads[1] = Access(w, ((), idx("i1")), False)
+        t.reads[2] = Access(b, (idx("i1"),), False)
+        self.g.add_task(t)
+        return out
+
+    def repeat_heads(self, x: str, groups: int) -> str:
+        """Grouped-query broadcast along axis 0: head ``h`` of the result
+        is head ``h // groups`` of ``x``."""
+        shp = self.shape[x]
+        groups = int(groups)
+        out = self.buf(self.fresh("rep"), (shp[0] * groups, *shp[1:]))
+        rest = [f"i{k}" for k in range(1, len(shp))]
+        self.g.add_task(Task(
+            self.fresh("repeat_heads_t"),
+            loops=[Loop("h", shp[0]), Loop("g", groups)]
+            + [Loop(d, int(n)) for d, n in zip(rest, shp[1:])],
+            reads=[Access(x, (idx("h"), *full_index(rest)), False)],
+            writes=[Access(out, (idx(("h", groups), "g"), *full_index(rest)),
+                           True)],
+            op="copy", flops_per_iter=0.0,
+            spec=OpSpec("repeat_heads", (x,), (out,), {"groups": groups})))
         return out
 
     # ---- recurrences -------------------------------------------------------
@@ -731,8 +886,18 @@ def trace_io(fn: Callable, *specs, name: str | None = None,
     return tr.gb.g, tr.inputs[:], _finish(tr, fn, args)
 
 
+# The trace a traced function is running in, for :func:`region`.
+_TRACING: contextvars.ContextVar[Tracer | None] = contextvars.ContextVar(
+    "codo_tracing", default=None)
+_REGION_NAME = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+
+
 def _finish(tr: Tracer, fn: Callable, args: list[ShapedBuffer]) -> list[str]:
-    tr.finish(fn(*args))
+    token = _TRACING.set(tr)
+    try:
+        tr.finish(fn(*args))
+    finally:
+        _TRACING.reset(token)
     return tr.outputs[:]
 
 
@@ -799,10 +964,19 @@ def _lift(tr: Tracer, v) -> str:
     return tr.gb.const(v)
 
 
-def _lift_ewise(tr: Tracer, a, b) -> tuple[str, str]:
+def _broadcast_index(shape, out_shape, dims):
+    """Index of an operand of ``shape`` broadcast to ``out_shape`` under
+    loop vars ``dims``: a size-1 dim reads a constant index, missing
+    leading dims are left out."""
+    lead = len(out_shape) - len(shape)
+    return tuple(idx(dims[lead + i]) if n == out_shape[lead + i] else ()
+                 for i, n in enumerate(shape))
+
+
+def _lift_ewise(tr: Tracer, a, b, broadcast: bool = False) -> tuple[str, str]:
     na, nb = _lift(tr, a), _lift(tr, b)
     sa, sb = tr.gb.shape[na], tr.gb.shape[nb]
-    if sa != sb:
+    if sa != sb and not broadcast:
         raise TraceError(
             f"elementwise operands must have identical shapes inside a "
             f"trace (got {sa} and {sb}; broadcasting is not part of the "
@@ -862,16 +1036,18 @@ def gelu(x):
 
 
 def add(a, b):
+    """``a + b``; array operands broadcast by numpy's rules."""
     tr = _tracer_of(a, b)
     if tr is None:
         return _eager("add", (a, b))
     for x, c in ((a, _as_scalar(b)), (b, _as_scalar(a))):
         if c is not None:                    # x + c == c + x, bit-exact
             return tr.wrap(tr.gb.affine(tr.name_of(x), 1.0, c))
-    return tr.wrap(tr.gb.add(*_lift_ewise(tr, a, b)))
+    return tr.wrap(tr.gb.add(*_lift_ewise(tr, a, b, broadcast=True)))
 
 
 def sub(a, b):
+    """``a - b``; array operands broadcast by numpy's rules."""
     tr = _tracer_of(a, b)
     if tr is None:
         return _eager("vadd", (a, b), {"alpha": 1.0, "beta": -1.0})
@@ -881,17 +1057,19 @@ def sub(a, b):
     c = _as_scalar(a)
     if c is not None:                        # c - x == (-x) + c, bit-exact
         return tr.wrap(tr.gb.affine(tr.name_of(b), -1.0, c))
-    return tr.wrap(tr.gb.vadd(*_lift_ewise(tr, a, b), alpha=1.0, beta=-1.0))
+    return tr.wrap(tr.gb.vadd(*_lift_ewise(tr, a, b, broadcast=True),
+                              alpha=1.0, beta=-1.0))
 
 
 def mul(a, b):
+    """``a * b``; array operands broadcast by numpy's rules."""
     tr = _tracer_of(a, b)
     if tr is None:
         return _eager("mul", (a, b))
     for x, c in ((a, _as_scalar(b)), (b, _as_scalar(a))):
         if c is not None:                    # x * c == c * x, bit-exact
             return tr.wrap(tr.gb.scale(tr.name_of(x), c))
-    return tr.wrap(tr.gb.mul(*_lift_ewise(tr, a, b)))
+    return tr.wrap(tr.gb.mul(*_lift_ewise(tr, a, b, broadcast=True)))
 
 
 def div(a, b):
@@ -944,13 +1122,130 @@ def mv(A, x, trans: bool = False):
     return _eager("mv", (A, x), {"trans": bool(trans)})
 
 
-def transpose(x):
+def transpose(x, axes=None):
+    """Swap the last two dims (2-D or batched 3-D), or permute by ``axes``:
+    result dim ``j`` is ``x``'s dim ``axes[j]``."""
     tr = _tracer_of(x)
     if tr is not None:
-        return tr.wrap(tr.gb.transpose(tr.name_of(x)))
+        return tr.wrap(tr.gb.transpose(tr.name_of(x), axes))
+    if axes is not None:
+        return _eager("transpose", (x,),
+                      {"perm": tuple(int(a) % x.ndim for a in axes)})
     if getattr(x, "ndim", 2) == 3:       # batched: swap the last two dims
         return _eager("transpose", (x,), {"perm": (0, 2, 1)})
     return _eager("transpose", (x,))
+
+
+def reshape(x, shape):
+    """Row-major reshape to ``shape`` (no ``-1``)."""
+    shape = tuple(int(s) for s in shape)
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.reshape(tr.name_of(x), shape))
+    return _eager("reshape", (x,), {"shape": shape})
+
+
+def param(shape):
+    """A learned parameter of ``shape`` (a bias, a norm's scale, a per-head
+    constant) in the trace being run.  Outside a trace, the deterministic
+    :func:`weight_init` of its shape."""
+    tr = _TRACING.get()
+    if tr is not None:
+        return tr.wrap(tr.gb.param(shape))
+    return weight_init(shape)
+
+
+def _unary(kind: str, x, flops: float = 1.0, attrs: dict | None = None):
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.unary(kind, tr.name_of(x), flops, attrs))
+    return _eager(kind, (x,), attrs)
+
+
+def silu(x):
+    return _unary("silu", x, 4.0)
+
+
+def softplus(x):
+    return _unary("softplus", x, 4.0)
+
+
+def exp(x):
+    return _unary("exp", x, 4.0)
+
+
+def causal_mask(x, diagonal: int = 0, fill: float = -np.inf):
+    """``fill`` where, in the last two axes, column − row exceeds
+    ``diagonal``; the rest unchanged.  The default, −inf strictly above
+    the diagonal (key after query), masks causal attention scores and the
+    SSD's segment sums before their ``exp``; ``diagonal=-1, fill=0``
+    keeps what lies strictly below the diagonal."""
+    attrs = {}
+    if diagonal != 0 or fill != -np.inf:
+        attrs = {"diagonal": int(diagonal), "fill": float(fill)}
+    return _unary("causal_mask", x, 1.0, attrs)
+
+
+def broadcast_to(x, shape):
+    """``x`` broadcast to ``shape`` by numpy's rules."""
+    shape = tuple(int(s) for s in shape)
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.broadcast_to(tr.name_of(x), shape))
+    return _eager("bcast", (x,), {"shape": shape})
+
+
+def cumsum(x, axis: int):
+    """Inclusive cumulative sum along ``axis``."""
+    axis = int(axis) % len(x.shape)
+    return _unary("cumsum", x, 1.0, {"axis": axis})
+
+
+def rmsnorm(x, eps: float = 1e-5):
+    """RMSNorm over the last axis with a learned scale (created here, like
+    :func:`fc`'s weight): ``x / sqrt(mean(x²) + eps) * w``."""
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.rmsnorm(tr.name_of(x), eps))
+    return _eager("rmsnorm", (x, weight_init((x.shape[-1],))),
+                  {"eps": float(eps)})
+
+
+def conv1d_causal(x, k: int):
+    """Depthwise causal convolution of width ``k`` with a bias over the
+    rows of ``x (S, C)``: taps ``(k, C)`` and bias ``(C,)`` are created
+    here, in that order."""
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.conv1d_causal(tr.name_of(x), int(k)))
+    c = x.shape[1]
+    return _eager("conv1d_causal", (x, weight_init((k, c)), weight_init((c,))))
+
+
+def repeat_heads(x, groups: int):
+    """Grouped-query broadcast of K/V heads along axis 0: head ``h`` of the
+    result is head ``h // groups`` of ``x``."""
+    tr = _tracer_of(x)
+    if tr is not None:
+        return tr.wrap(tr.gb.repeat_heads(tr.name_of(x), groups))
+    return _eager("repeat_heads", (x,), {"groups": int(groups)})
+
+
+@contextlib.contextmanager
+def region(name: str):
+    """Tag every task traced inside the block with region ``name`` (nested
+    regions: the innermost wins).  The lowering names a fusion group's ops
+    ``<region>.g<gid>.<kernel>`` when all of its tasks share one region.
+    Outside a trace it does nothing."""
+    if not _REGION_NAME.fullmatch(name):
+        raise TraceError(f"region name {name!r} is not an identifier")
+    tr = _TRACING.get()
+    first = len(tr.gb.g.tasks) if tr is not None else 0
+    yield
+    if tr is not None:
+        for t in tr.gb.g.tasks[first:]:
+            if task_region(t) is None:
+                t.tags.add(REGION_TAG + name)
 
 
 def fc(x, dout: int, relu: bool = False, weight=None):
@@ -1217,9 +1512,12 @@ __all__ = [
     "BATCHABLE_KINDS", "GB", "ShapedBuffer", "TraceError", "Tracer",
     "batch_blockers", "batch_graph", "buffer", "trace",
     "trace_io", "weight_init",
+    "region",
     # ops
-    "add", "concat", "conv", "div", "fc", "flatten", "gelu",
-    "global_avgpool", "load", "matmul", "maxpool", "mean_all", "mul", "mv",
-    "pad", "relu", "rglru_scan", "scale", "slice_", "softmax", "split",
-    "ssd_scan", "sub", "transpose", "vadd",
+    "add", "broadcast_to", "causal_mask", "concat", "conv", "conv1d_causal", "cumsum",
+    "div", "exp", "fc", "flatten", "gelu", "global_avgpool", "load",
+    "matmul", "maxpool", "mean_all", "mul", "mv", "pad", "param", "relu",
+    "repeat_heads", "reshape", "rglru_scan", "rmsnorm", "scale", "silu",
+    "slice_", "softmax", "softplus", "split", "ssd_scan", "sub",
+    "transpose", "vadd",
 ]

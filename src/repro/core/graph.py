@@ -401,6 +401,19 @@ def retarget_fn(fn: Callable[[dict], dict], alias: dict[str, str]) -> Callable[[
     return wrapped
 
 
+# A task traced inside ``frontend.region(name)`` carries the tag
+# ``region:<name>``; the lowering names its fusion group's ops after it.
+REGION_TAG = "region:"
+
+
+def task_region(task: Task) -> str | None:
+    """The region ``task`` was traced in, or ``None``."""
+    for tag in task.tags:
+        if tag.startswith(REGION_TAG):
+            return tag[len(REGION_TAG):]
+    return None
+
+
 # --------------------------------------------------------------------------
 # Graph
 # --------------------------------------------------------------------------
@@ -455,13 +468,29 @@ class DataflowGraph:
                 return t
         raise KeyError(name)
 
+    def access_index(self) -> tuple[dict[str, list[Task]],
+                                    dict[str, list[Task]]]:
+        """``(writers, readers)``: buffer -> the tasks that write (read) it,
+        in task order, built in one sweep over the tasks (what
+        :meth:`producers` and :meth:`consumers` give buffer by buffer)."""
+        writers: dict[str, list[Task]] = {}
+        readers: dict[str, list[Task]] = {}
+        for t in self.tasks:
+            for b in dict.fromkeys(a.buffer for a in t.writes):
+                writers.setdefault(b, []).append(t)
+            for b in dict.fromkeys(a.buffer for a in t.reads):
+                readers.setdefault(b, []).append(t)
+        return writers, readers
+
     def edges(self) -> list[tuple[Task, str, Task]]:
-        """(producer, buffer, consumer) triples."""
+        """(producer, buffer, consumer) triples, by buffer in table order,
+        then producer and consumer in task order."""
+        writers, readers = self.access_index()
         out = []
-        for buf in self.buffers.values():
-            for p in self.producers(buf.name):
-                for c in self.consumers(buf.name):
-                    out.append((p, buf.name, c))
+        for name in self.buffers:
+            for p in writers.get(name, ()):
+                for c in readers.get(name, ()):
+                    out.append((p, name, c))
         return out
 
     def internal_edges(self) -> list[tuple[Task, str, Task]]:
@@ -495,8 +524,9 @@ class DataflowGraph:
                     if p != t.name:
                         succ[p].append(t.name)
                         indeg[t.name] += 1
+        pos = {t.name: i for i, t in enumerate(self.tasks)}
         order, queue = [], sorted([t.name for t in self.tasks if indeg[t.name] == 0],
-                                  key=lambda n: [t.name for t in self.tasks].index(n))
+                                  key=pos.__getitem__)
         while queue:
             n = queue.pop(0)
             order.append(n)
@@ -673,17 +703,23 @@ def matmul_task(
     n: int,
     k: int,
     fn: Callable[[dict], dict] | None = None,
-    batch: int = 0,
+    batch: int | tuple[int, ...] = 0,
     spec: OpSpec | None = None,
 ) -> Task:
     """out[m,n] += lhs[m,k] * rhs[k,n]; the write sits inside the k
     reduction — the canonical access-count-mismatch producer Fig. 5
     rewrites — and the lhs read repeats across n — the broadcast re-read
-    the reuse pass caches."""
+    the reuse pass caches.  ``batch``: one leading batch loop ``b``, or a
+    tuple of them, ``b0, b1, ...``."""
     loops, out_idx, l_idx, r_idx = [], [], [], []
-    if batch:
-        loops.append(Loop("b", batch))
-        out_idx.append(idx("b")); l_idx.append(idx("b")); r_idx.append(idx("b"))
+    if isinstance(batch, tuple):
+        batch_loops = [Loop(f"b{i}", int(n)) for i, n in enumerate(batch)]
+    else:
+        batch_loops = [Loop("b", batch)] if batch else []
+    for bl in batch_loops:
+        loops.append(bl)
+        for ix in (out_idx, l_idx, r_idx):
+            ix.append(idx(bl.var))
     loops += [Loop("m", m), Loop("n", n), Loop("k", k)]
     out_idx += [idx("m"), idx("n")]
     l_idx += [idx("m"), idx("k")]

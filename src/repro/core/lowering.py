@@ -45,7 +45,7 @@ from jax.profiler import TraceAnnotation
 
 from .compiler import CompiledDataflow
 from .costmodel import device_hw
-from .graph import FIFO, DataflowGraph, GraphError, Task
+from .graph import FIFO, DataflowGraph, GraphError, Task, task_region
 from .ops import registry_epoch as _ops_epoch
 from .routing import (XLA_FUSED, KernelPattern, RoutedKernel,
                       ensure_kernel_patterns, pallas_interpret_forced,
@@ -160,11 +160,15 @@ _COUNT_LOCK = threading.Lock()
 # Memo hits and misses; per jitted program (keyed by its name,
 # ``codo_<graph>``), the calls (``dispatches``), the runs of its Python
 # body (``traces``): under jit, only when JAX traces it — a compile or a
-# persistent-cache load — and on every call without jit; and the conv
-# tasks it runs as patch matmuls (``patch_convs``, set at lowering).
+# persistent-cache load — and on every call without jit; the conv tasks it
+# runs as patch matmuls (``patch_convs``, set at lowering); and the
+# candidate chains the gate declined (``declined``, set at lowering): a
+# Counter keyed by (pattern, reason), the reason ``infeasible`` (the
+# pattern's guard), ``cost`` (the cost model or tuning database), ``vmem``
+# (the factory: a grid step's blocks exceed VMEM) or ``sharded``.
 LOWER_CACHE_STATS = {"hits": 0, "misses": 0,
                      "dispatches": Counter(), "traces": Counter(),
-                     "patch_convs": Counter()}
+                     "patch_convs": Counter(), "declined": {}}
 
 
 def _lower_cache_size() -> int:
@@ -179,6 +183,7 @@ def clear_lower_cache() -> None:
         LOWER_CACHE_STATS["dispatches"].clear()
         LOWER_CACHE_STATS["traces"].clear()
         LOWER_CACHE_STATS["patch_convs"].clear()
+        LOWER_CACHE_STATS["declined"].clear()
 
 
 def program_name(graph: DataflowGraph) -> str:
@@ -196,7 +201,9 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
     (every external operand of every matched task is in scope by then);
     everything else runs task by task (XLA still fuses under jit).  The
     scope, ``g<gid>.<kernel>`` for a routed chain and ``g<gid>.xla`` for
-    the rest, names the step's ops in the compiled program."""
+    the rest, names the step's ops in the compiled program; a task traced
+    in a ``frontend.region`` prefixes it with the region's name (per step:
+    one fusion group can span many layers)."""
     step_at: dict[str, tuple[str, Callable[[dict], dict]]] = {}
     skip: set[str] = set()
     if use_registered_kernels:
@@ -243,8 +250,25 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
             kernel, fn = "patchconv", _patch_conv_step(t.spec)
         else:
             kernel, fn = "xla", t.fn
-        steps.append((t.name, f"g{t.fused_group}.{kernel}", fn))
+        region = task_region(t)
+        steps.append((t.name, (f"{region}." if region else "")
+                      + f"g{t.fused_group}.{kernel}", fn))
     return steps
+
+
+# Why a candidate chain stayed generic, by its route's decision.
+_DECLINE_REASON = {"predicted-loss": "cost", "tuned-generic": "cost",
+                   "declined": "vmem", "sharded": "sharded"}
+
+
+def declined_chains(groups: list[FusionGroup],
+                    infeasible: list) -> Counter:
+    """(pattern, reason) -> candidate chains the gate declined."""
+    out = Counter(infeasible)
+    for g in groups:
+        for r in g.rejected:
+            out[(r.kernel, _DECLINE_REASON.get(r.decision, r.decision))] += 1
+    return out
 
 
 # An ungrouped convolution with few input channels leaves the 128 lanes of
@@ -445,9 +469,10 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
                                   hit.patch_convs)
     impl = compiled.buffer_plan.impl if compiled.buffer_plan else {}
     groups = fusion_groups(graph, impl)
+    infeasible: list = []
     if use_registered_kernels:
         route_groups(graph, groups, impl,
-                     hw=device_hw(compiled.options.hw))
+                     hw=device_hw(compiled.options.hw), declined=infeasible)
     if sharding is not None:
         _drop_sharded_routes(groups, sharding)
     steps = _build_steps(graph, groups, use_registered_kernels)
@@ -483,6 +508,8 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
     patch_convs = sum(scope.endswith(".patchconv") for _n, scope, _f in steps)
     with _COUNT_LOCK:
         LOWER_CACHE_STATS["patch_convs"][name] = patch_convs
+        LOWER_CACHE_STATS["declined"][name] = declined_chains(groups,
+                                                              infeasible)
     out = LoweredProgram(graph, groups, fn, materialized, name, patch_convs)
     _record_routing(compiled, groups)
     if memo:

@@ -475,6 +475,76 @@ def _slice(spec: OpSpec, env: dict) -> dict:
     return {spec.outs[0]: x[ix]}
 
 
+@register_op("rmsnorm")
+def _rmsnorm(spec: OpSpec, env: dict) -> dict:
+    """``x / sqrt(mean(x², -1) + eps) * w`` over the last axis."""
+    import jax
+    import jax.numpy as jnp
+    x, w = env[spec.ins[0]], env[spec.ins[1]]
+    ms = jnp.mean(x * x, axis=-1, keepdims=True)
+    return {spec.outs[0]: x * jax.lax.rsqrt(ms + float(spec.attrs["eps"])) * w}
+
+
+@register_op("silu")
+def _silu(spec: OpSpec, env: dict) -> dict:
+    import jax
+    return {spec.outs[0]: jax.nn.silu(env[spec.ins[0]])}
+
+
+@register_op("softplus")
+def _softplus(spec: OpSpec, env: dict) -> dict:
+    import jax
+    return {spec.outs[0]: jax.nn.softplus(env[spec.ins[0]])}
+
+
+@register_op("exp")
+def _exp(spec: OpSpec, env: dict) -> dict:
+    import jax.numpy as jnp
+    return {spec.outs[0]: jnp.exp(env[spec.ins[0]])}
+
+
+@register_op("cumsum")
+def _cumsum(spec: OpSpec, env: dict) -> dict:
+    import jax.numpy as jnp
+    return {spec.outs[0]: jnp.cumsum(env[spec.ins[0]],
+                                     axis=int(spec.attrs["axis"]))}
+
+
+@register_op("causal_mask")
+def _causal_mask(spec: OpSpec, env: dict) -> dict:
+    """``fill`` (−inf) where, in the last two axes, column − row exceeds
+    ``diagonal`` (0: strictly above the diagonal)."""
+    import jax.numpy as jnp
+    x = env[spec.ins[0]]
+    rows, cols = x.shape[-2:]
+    keep = (jnp.arange(cols)[None, :] - jnp.arange(rows)[:, None]
+            <= int(spec.attrs.get("diagonal", 0)))
+    return {spec.outs[0]: jnp.where(keep, x, float(spec.attrs.get(
+        "fill", -jnp.inf)))}
+
+
+@register_op("conv1d_causal")
+def _conv1d_causal(spec: OpSpec, env: dict) -> dict:
+    """Depthwise causal convolution over the rows of ``x (S, C)`` with taps
+    ``w (K, C)`` and bias ``b (C,)``: ``y[t] = b + Σ_k w[k] x[t - K + 1 + k]``,
+    zero before the first row."""
+    import jax.numpy as jnp
+    x, w, b = (env[n] for n in spec.ins)
+    k, s = w.shape[0], x.shape[0]
+    xp = jnp.pad(x, ((k - 1, 0), (0, 0)))
+    y = b + sum(w[i] * xp[i:i + s] for i in range(k))
+    return {spec.outs[0]: y}
+
+
+@register_op("repeat_heads")
+def _repeat_heads(spec: OpSpec, env: dict) -> dict:
+    """Grouped-query broadcast: each head of axis 0 repeated ``groups``
+    times in place, so head ``h`` of the result is head ``h // groups``."""
+    import jax.numpy as jnp
+    return {spec.outs[0]: jnp.repeat(env[spec.ins[0]],
+                                     int(spec.attrs["groups"]), axis=0)}
+
+
 def _rglru_reference(a, b):
     """RG-LRU recurrence h_t = a_t * h_{t-1} + b_t over axis 1 of (B, S, D)
     operands, h_{-1} = 0 — shared by the generic impl and its VJP (the VJP
@@ -962,15 +1032,16 @@ def _mv_vjp(spec, g, b):
 
 @register_vjp("transpose")
 def _transpose_vjp(spec, g, b):
-    # Both emitted perms (2-D T, batched (0, 2, 1)) are self-inverse.
-    return {spec.ins[0]: b.transpose(g[spec.outs[0]])}
+    perm = spec.attrs.get("perm")
+    if perm is None:                # the 2-D T is self-inverse
+        return {spec.ins[0]: b.transpose(g[spec.outs[0]])}
+    inverse = tuple(sorted(range(len(perm)), key=lambda i: perm[i]))
+    return {spec.ins[0]: b.transpose(g[spec.outs[0]], inverse)}
 
 
 @register_vjp("reshape")
 def _reshape_vjp(spec, g, b):
-    x_shape = b.shape(spec.ins[0])
-    return {spec.ins[0]: b.emit("reshape", (g[spec.outs[0]],), (x_shape,),
-                                {"shape": x_shape}, op="copy", flops=0.0)[0]}
+    return {spec.ins[0]: b.gb.reshape(g[spec.outs[0]], b.shape(spec.ins[0]))}
 
 
 @register_vjp("concat")

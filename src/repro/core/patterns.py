@@ -178,13 +178,14 @@ def arrival_order(task: Task, access: Access) -> tuple[int, ...]:
 
 def coarse_violations(graph: DataflowGraph) -> list[CoarseViolation]:
     out = []
+    writers, readers = graph.access_index()
     for buf in graph.buffers.values():
         if buf.kind in ("input", "weight"):
             # External inputs may fan out freely: duplication happens at the
             # off-chip boundary (each consumer DMAs its own stream).
             continue
-        prods = graph.producers(buf.name)
-        cons = graph.consumers(buf.name)
+        prods = writers.get(buf.name, [])
+        cons = readers.get(buf.name, [])
         np_, nc = len(prods), len(cons)
         if np_ <= 1 and nc <= 1:
             continue

@@ -56,11 +56,16 @@ class ReuseReport:
 
 
 def _stencil_read(task: Task):
+    """The read whose index sums two or more live loops, one of them a
+    window loop the task's writes do not index (conv's kh/kw, a pool's
+    window).  A read whose loops the writes all index (a reshape's
+    regrouping) is a plain stream."""
+    written = {v for a in task.writes for v in a.vars()}
     for a in task.reads:
         for dim in a.index:
             live = [v for (v, _s) in dim
                     if task.has_loop(v) and task.loop(v).trip > 1]
-            if len(live) > 1:
+            if len(live) > 1 and not set(live) <= written:
                 return a
     return None
 
@@ -91,14 +96,18 @@ def rewrite_stencil_task(graph: DataflowGraph, task: Task, itemsize: int = 4
             new_index.append(idx(pos))
         else:
             new_index.append(idx(*live) if live else ())
+    # Vars in loop order, so that the enclosing sets below do not depend
+    # on the iteration order of a set of strings (which varies between
+    # processes, and with it the structural hash).
+    depth0 = {l.var: i for i, l in enumerate(task.loops)}
     read_only = read.vars() - write.vars()          # e.g. {ci, kh, kw}
-    write_only = write.vars() - read.vars()         # e.g. {co}
-    ci_vars = [v for v in read_only if v not in kernel and v not in spatial]
-    batch = [v for v in (read.vars() & write.vars())
-             if v not in spatial and v not in kernel]
+    write_only = sorted(write.vars() - read.vars(), key=depth0.get)  # [co]
+    ci_vars = sorted((v for v in read_only
+                      if v not in kernel and v not in spatial), key=depth0.get)
+    batch = sorted((v for v in read.vars() & write.vars()
+                    if v not in spatial and v not in kernel), key=depth0.get)
 
     # --- new loop order (Fig. 7): batch, spatial, [load ci | compute co,ci,k...]
-    depth0 = {l.var: i for i, l in enumerate(task.loops)}
 
     def order_key(l):
         if l.var in batch:

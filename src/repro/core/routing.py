@@ -320,6 +320,7 @@ def _chain_is_exclusive(tasks: list[Task]) -> bool:
 def match_group(graph: DataflowGraph, group_tasks: Sequence[str],
                 impl: dict[str, str], *,
                 patterns: Sequence[KernelPattern] | None = None,
+                declined: list | None = None,
                 ) -> list[tuple[KernelPattern, list[Task]]]:
     """All non-overlapping pattern matches inside one fusion group.
 
@@ -331,7 +332,9 @@ def match_group(graph: DataflowGraph, group_tasks: Sequence[str],
     ``flashattn.mha`` takes ``matmul→scale→softmax→matmul`` whole even
     though ``streamfuse.mmchain`` could claim the score matmul from the
     projection anchor and ``streamfuse.softmaxmm`` could claim the tail.
-    The result is returned in anchor topo order.
+    The result is returned in anchor topo order.  Structural matches
+    whose ``feasible`` guard fails are appended to ``declined`` as
+    ``(pattern name, "infeasible")``.
     """
     pats = list(patterns) if patterns is not None else registered_patterns()
     if not pats:
@@ -346,6 +349,8 @@ def match_group(graph: DataflowGraph, group_tasks: Sequence[str],
             if not tasks or len(tasks) < min_len:
                 continue            # single-task "chains" stay with XLA
             if pat.feasible is not None and not pat.feasible(graph, tasks):
+                if declined is not None:
+                    declined.append((pat.name, "infeasible"))
                 continue
             candidates.append((-len(tasks), a_idx, p_idx, pat, tasks))
     claimed: set[str] = set()
@@ -395,11 +400,12 @@ def decide_route(graph: DataflowGraph, tasks: list[Task],
 
 def route_groups(graph: DataflowGraph, groups, impl: dict[str, str], *,
                  enabled: bool | None = None, hw=None, params=None,
-                 db=None) -> None:
+                 db=None, declined: list | None = None) -> None:
     """Annotate each :class:`~repro.core.lowering.FusionGroup` in
     ``groups`` with its routing decision: cost-gate-accepted chains in
     ``routes``, gate-rejected structural matches in ``rejected``, and the
-    group-level predicted cycles both ways.
+    group-level predicted cycles both ways.  Matches the feasibility
+    guards refuse go to ``declined`` (see :func:`match_group`).
 
     ``enabled=None`` consults :func:`pallas_disabled`.  jax-free: only the
     lowering turns the resulting decisions into executable steps.
@@ -416,7 +422,8 @@ def route_groups(graph: DataflowGraph, groups, impl: dict[str, str], *,
         g.decision = "disabled" if not enabled else "generic"
         chained: set[str] = set()
         if enabled and g.tasks:
-            for pat, tasks in match_group(graph, g.tasks, impl):
+            for pat, tasks in match_group(graph, g.tasks, impl,
+                                          declined=declined):
                 route = decide_route(graph, tasks, pat, hw=hw,
                                      params=params, db=db)
                 (g.routes if route.routed else g.rejected).append(route)

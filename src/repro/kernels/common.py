@@ -42,6 +42,18 @@ def vmem_ok(*shapes) -> bool:
     return sum(int(np.prod(s)) for s in shapes) * 4 <= VMEM_BUDGET_BYTES
 
 
+def vmem_tile(shape) -> tuple[int, ...]:
+    """``shape`` as it lies in VMEM: the last dim padded to the 128 lanes,
+    the one before it to the 8 sublanes of an f32 tile."""
+    shape = tuple(int(s) for s in shape)
+    if not shape:
+        return (8, 128)
+    *lead, last = shape
+    if lead:
+        lead[-1] = -(-lead[-1] // 8) * 8
+    return (*lead, -(-last // 128) * 128)
+
+
 def all_f32(graph, *bufs) -> bool:
     return all(np.dtype(graph.buffers[b].dtype) == np.float32 for b in bufs)
 

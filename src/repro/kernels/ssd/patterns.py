@@ -12,7 +12,7 @@ from __future__ import annotations
 import functools
 
 from ...core.routing import KernelPattern, register_kernel_pattern
-from ..common import all_f32, kernel_mode, vmem_ok
+from ..common import all_f32, kernel_mode, vmem_ok, vmem_tile
 
 
 def _feasible(graph, tasks) -> bool:
@@ -30,6 +30,16 @@ def _feasible(graph, tasks) -> bool:
     return all_f32(graph, st_buf, dec_buf, out_buf)
 
 
+def step_vmem_ok(states_shape) -> bool:
+    """What one grid step of the kernel holds in VMEM: one chunk's state
+    block and decay block in, the carried-in state block out (each
+    double-buffered), and the running state."""
+    _nc, bh, p, n = states_shape
+    state = vmem_tile((bh, p, n))
+    return vmem_ok((2, *state), (2, *vmem_tile((bh, 1, 1))), (2, *state),
+                   state)
+
+
 def factory(graph, group, tasks, tile=None):
     import jax
 
@@ -38,8 +48,8 @@ def factory(graph, group, tasks, tile=None):
     out_buf = t.spec.outs[0]
 
     mode = kernel_mode()
-    if mode == "pallas" and not vmem_ok(graph.buffers[st_buf].shape):
-        return None
+    if mode == "pallas" and not step_vmem_ok(graph.buffers[st_buf].shape):
+        return None                     # a grid step's blocks exceed VMEM
 
     if mode == "reference":
         from .ref import ssd_chunk_scan_ref

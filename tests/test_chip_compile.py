@@ -154,3 +154,66 @@ def test_kernel_compiles(one_chip, name, shapes):
               "mmgrad": "streamfuse_mmgrad"}.get(name, name.replace(".", "_"))
     assert _kernel_names(_compile(fn, shapes, one_chip)) == {kernel}
 
+
+
+def _granite():
+    """granite-4.0-h-micro's period (the benchmark's configuration) and its
+    sizes at the published widths."""
+    import importlib.util
+    import json
+    from pathlib import Path
+    configs = Path(__file__).resolve().parents[1] / "bench" / "configs"
+    name = "granite-4.0-h-micro-period"
+    spec = importlib.util.spec_from_file_location("granite_period",
+                                                  configs / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod, mod._sizes(json.loads((configs / f"{name}.json").read_text()))
+
+
+def _granite_route(fn, shape, pattern):
+    """The first ``pattern`` chain of ``fn`` traced at ``shape``: (step,
+    shapes of the buffers the step reads)."""
+    import repro.api as codo
+    from repro.core.lowering import fusion_groups
+    from repro.core.routing import ensure_kernel_patterns, match_group
+    ensure_kernel_patterns()
+    c = codo.compile(fn, shape, cache=None).compiled
+    graph, impl = c.graph, c.buffer_plan.impl
+    for g in fusion_groups(graph, impl):
+        for pat, tasks in match_group(graph, g.tasks, impl):
+            if pat.name == pattern:
+                return graph, pat.factory(graph, g, tasks), tasks
+    raise AssertionError(f"no {pattern} chain")
+
+
+def test_granite_attention_route_compiles(one_chip, on_tpu):
+    """The causal GQA chain at granite's widths (32 query heads over 8 K/V
+    heads of 64, S = 4096): its K/V are 16 MiB whole, a grid step holds
+    128-row blocks; the step reads the 8 K/V heads, not repeated copies."""
+    from repro.kernels.flashattn.patterns import kv_sources
+    mod, z = _granite()
+    graph, step, tasks = _granite_route(
+        functools.partial(mod._attention, z), (4096, 2048), "flashattn.mha")
+    assert step is not None
+    kt, v, group = kv_sources(graph, tasks)
+    assert group == 4 and graph.buffers[kt].shape == (8, 64, 4096)
+    q = tasks[0].spec.ins[0]
+    shapes = {b: tuple(graph.buffers[b].shape) for b in (q, kt, v)}
+    assert _kernel_names(_compile(step, shapes, one_chip)) == {"flashattn_mha"}
+
+
+def test_granite_ssd_route_compiles(one_chip, on_tpu):
+    """The SSD chunk scan at granite's widths: 16 chunks of 64 heads of
+    64 x 128 state; 32 MiB whole, 2 MiB a chunk."""
+    import repro.api as codo
+    from repro.core import frontend as F
+    from repro.kernels.ssd.patterns import factory
+    _mod, z = _granite()
+    shapes = [(16, z["H"], z["P"], z["N"]), (16, z["H"], 1, 1)]
+    graph = codo.compile(F.ssd_scan, *shapes, cache=None).compiled.graph
+    (task,) = [t for t in graph.tasks if t.spec.kind == "ssd_scan"]
+    step = factory(graph, None, [task])
+    assert step is not None
+    env = dict(zip(task.spec.ins, shapes))
+    assert _kernel_names(_compile(step, env, one_chip)) == {"ssd_scan"}
