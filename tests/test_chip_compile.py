@@ -10,7 +10,8 @@ Widths are the main path's: the ``gpt2_block`` at GPT-2-medium width
 softmax·matmul and gradient-epilogue kernels at 1024², the RG-LRU scan at
 recurrentgemma-9b's width (one sequence of 1024 x 4096), the SSD chunk
 scan at mamba2-780m's (48 heads of 64 x 128 state, 4 chunks of 256
-tokens) and the conv chain at a resnet18 stage-1 layer.
+tokens) and the conv chain at a resnet18 stage-1 layer; besides the
+kernels, granite's cumulative sums as triangular matmuls.
 
 The topology is described in a fixture (never at import), and jax's
 persistent compilation cache is off around the compiles: an entry written
@@ -217,3 +218,24 @@ def test_granite_ssd_route_compiles(one_chip, on_tpu):
     assert step is not None
     env = dict(zip(task.spec.ins, shapes))
     assert _kernel_names(_compile(step, env, one_chip)) == {"ssd_scan"}
+
+
+@pytest.mark.parametrize("shape,axis", [
+    ((16, 64, 256, 256), 2),          # the segment sums of a Mamba-2 layer
+    ((16, 256, 64), 1),               # the chunk cumsum of its steps
+])
+def test_granite_cumsums_compile_as_contractions(one_chip, shape, axis):
+    """granite's two cumulative sums a Mamba-2 layer, at published widths,
+    lowered as triangular matmuls: contractions at ``HIGHEST``, and no
+    reduce-window over the scanned axis (one may remain over the block
+    totals, a block-count long)."""
+    import math
+    import re
+    from repro.core.lowering import TRI_CUMSUM_BLOCK, tri_cumsum
+    text = _compile(lambda x: tri_cumsum(x, axis), [shape], one_chip)
+    assert "operand_precision={highest,highest}" in text
+    n = shape[axis]
+    nb = -(-n // min(n, TRI_CUMSUM_BLOCK))
+    for dims in re.findall(r"= f32\[([\d,]+)\]\S* reduce-window\(", text):
+        assert math.prod(map(int, dims.split(","))) <= (
+            math.prod(shape) // n * nb)

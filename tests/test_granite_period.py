@@ -51,20 +51,26 @@ def cfg():
     return {**json.loads((CONFIGS / f"{NAME}.json").read_text()), **SMALL}
 
 
-@pytest.fixture(scope="module")
-def compiled(model, cfg):
-    """The period compiled once, with seeded weights and input."""
-    program = codo.compile(functools.partial(model.period, cfg), (S, 64),
+def _bound_period(model, cfg, s):
+    """The period compiled at ``s`` tokens, with seeded weights and input,
+    and the reference's output."""
+    program = codo.compile(functools.partial(model.period, cfg), (s, 64),
                            cache=None)
     rng = np.random.default_rng(11)
     ws = [(rng.standard_normal(b.shape)
            / np.sqrt(b.shape[0] if len(b.shape) == 2 else 1)).astype(
                np.float32) for b in program.source.weights()]
     program.bind(**{b.name: w for b, w in zip(program.source.weights(), ws)})
-    x = rng.standard_normal((S, 64)).astype(np.float32)
+    x = rng.standard_normal((s, 64)).astype(np.float32)
     want = np.asarray(jax.jit(lambda w, x: model.reference(
         cfg, _highest(), w, x))(ws, x))
     return program, ws, x, want
+
+
+@pytest.fixture(scope="module")
+def compiled(model, cfg):
+    """The period compiled once, with seeded weights and input."""
+    return _bound_period(model, cfg, S)
 
 
 def _rel(got, want):
@@ -105,6 +111,20 @@ def test_period_with_kernels_matches_reference(compiled, monkeypatch):
     # The same reassociation, plus the kernels' online softmax and their
     # own order of the state updates: 7e-7 observed.
     assert _rel(got, want) < 1e-5
+
+
+def test_lane_long_chunks_lower_cumsums_as_triangular_matmuls(model, cfg,
+                                                             compiled):
+    """At chunk 128 (S 256) both cumsums of each Mamba-2 layer, the chunk
+    cumsum of the steps and the segment sums, scan an axis a lane long and
+    run as triangular matmuls; at chunk 32 (the other cases) none does."""
+    assert lower(compiled[0].compiled, jit=True).tri_cumsums == 0
+    program, _ws, x, want = _bound_period(
+        model, {**cfg, "mamba_chunk_size": 128}, 256)
+    low = lower(program.compiled, jit=True)
+    assert low.tri_cumsums == 2 * model.layer_kinds(cfg).count("mamba")
+    # The same reassociation as at chunk 32, and the sums' own order.
+    assert _rel(program(x), want) < 1e-5
 
 
 def test_chunked_reference_ssd_matches_the_recurrence(model):
