@@ -1,7 +1,8 @@
 """Benchmark harness — one function per paper table/figure.  Prints
 ``name,value,derived`` CSV and writes results/bench/*.csv; ``--json``
 additionally collects every suite into one machine-readable document (what
-the nightly CI job uploads).
+the nightly CI job uploads).  Every suite prices with the cost model, so
+its numbers are the same on any host.
 
     PYTHONPATH=src python -m benchmarks.run              # everything
     PYTHONPATH=src python -m benchmarks.run --only table2,fig10
@@ -18,8 +19,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from benchmarks import (routing_bench, serving_bench,  # noqa: E402
-                        sharding_bench, tables, train_bench)
+from benchmarks import tables  # noqa: E402
 
 OUT = Path(__file__).resolve().parents[1] / "results" / "bench"
 
@@ -32,19 +32,6 @@ SUITES = {
     "table7": tables.table7_batch,
     "fig11": tables.parallelism_sweep,
     "table8": tables.fifo_percentage,
-    "micro": tables.kernel_microbench,
-    # per-group pallas-vs-xla latency pairs; also writes
-    # results/bench/routing_groups.json (uploaded by the nightly CI job)
-    "routing": routing_bench.routing_groups,
-    # batched-vs-sequential serving throughput + p50/p99; also writes
-    # results/bench/serving.json (uploaded by the nightly CI job)
-    "serving": serving_bench.serving_rows,
-    # per-device-count sharded scaling on gpt2_medium; also writes
-    # results/bench/sharding.json (uploaded by the sharding-smoke CI job)
-    "sharding": sharding_bench.sharding_rows,
-    # compiled-vs-plain-jit training step time (graph-level autodiff); also
-    # writes results/bench/training.json (uploaded by the training-smoke job)
-    "training": train_bench.training_rows,
 }
 
 

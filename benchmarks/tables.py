@@ -1,19 +1,18 @@
 """Benchmark implementations, one per paper table/figure (§VIII).
 
 Latency numbers are the cost model's cycle estimates (the role HLS
-synthesis reports play in the paper); wall-clock microbenches cover the
-runnable kernels.  Every function returns a list of CSV rows
-(name, value, derived).
+synthesis reports play in the paper): deterministic, on any host.  Every
+function returns a list of CSV rows (name, value, derived).
 """
 
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core import ABLATION_PRESETS, CodoOptions, PassManager, codo_opt
+from repro.core.compiler import format_batch_grid  # noqa: F401 — re-export
 from repro.models import dataflow_models as dm
 
 # For rows that *report* compile time as a paper metric: a real pipeline run
@@ -161,41 +160,9 @@ def ablation(budget: int = 2048) -> list[Row]:
 # --------------------------------------------------------------------------
 
 
-def format_batch_grid(results) -> str:
-    """Table VII-style text grid from ``codo_opt_batch`` results: one row
-    per config, one speedup column per preset.  Cached cells are marked
-    ``*`` (their compile time is the lookup, not a pipeline run)."""
-    presets = sorted({r.preset for r in results},
-                     key=lambda p: list(ABLATION_PRESETS).index(p)
-                     if p in ABLATION_PRESETS else 99)
-    configs = sorted({r.config for r in results})
-    by_cell = {(r.config, r.preset): r for r in results}
-
-    w = max([len(c) for c in configs] + [8])
-    head = f"{'config':<{w}s} " + " ".join(f"{p:>12s}" for p in presets) \
-        + "   fifo   compile_ms"
-    lines = [head, "-" * len(head)]
-    for cname in configs:
-        cells, fifo, ms = [], "", 0.0
-        for p in presets:
-            r = by_cell.get((cname, p))
-            if r is None or not r.ok:
-                cells.append(f"{'ERR':>12s}")
-                continue
-            mark = "*" if r.cache_hit else ""
-            cells.append(f"{r.compiled.speedup:>11.1f}{mark or 'x'}")
-            fifo = f"{r.compiled.fifo_fraction:.2f}"
-            ms += r.compiled.compile_seconds * 1e3
-        lines.append(f"{cname:<{w}s} " + " ".join(cells)
-                     + f"   {fifo:>4s}   {ms:>9.1f}")
-    lines.append("(speedup vs sequential baseline; '*' = compile-cache hit; "
-                 "fifo from the last preset column; compile_ms = row total "
-                 "across preset columns)")
-    return "\n".join(lines)
-
-
 def batch_grid_rows(results) -> list[Row]:
-    """CSV rows mirroring :func:`format_batch_grid` for results/bench.
+    """CSV rows mirroring :func:`~repro.core.compiler.format_batch_grid`
+    for results/bench.
     The derived string is BatchResult.derived() — one format, shared with
     the CLI's --csv output."""
     return [Row(f"table7/{r.config}/{r.preset}",
@@ -244,46 +211,4 @@ def fifo_percentage() -> list[Row]:
     for name, build in workloads.items():
         c = codo_opt(build())
         rows.append(Row(f"table8/{name}", c.fifo_fraction * 100, "% FIFO"))
-    return rows
-
-
-# --------------------------------------------------------------------------
-# Kernel wall-clock microbench (runnable numbers on this host)
-# --------------------------------------------------------------------------
-
-
-def kernel_microbench(iters: int = 20) -> list[Row]:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.models.layers import blockwise_attention, full_attention
-
-    rows = []
-    rng = np.random.default_rng(0)
-
-    def timeit(fn, *args):
-        fn(*args)[0].block_until_ready() if isinstance(fn(*args), tuple) \
-            else jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e6
-
-    B, H, S, hd = 1, 4, 1024, 64
-    q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
-    k, v = q, q
-    blk = jax.jit(lambda q, k, v: blockwise_attention(q, k, v, causal=True))
-    ful = jax.jit(lambda q, k, v: full_attention(q, k, v, causal=True))
-    rows.append(Row("micro/blockwise_attn_us", timeit(blk, q, k, v),
-                    f"S={S} flash-recurrence jnp"))
-    rows.append(Row("micro/full_attn_us", timeit(ful, q, k, v),
-                    f"S={S} materialized scores"))
-
-    from repro.kernels.streamfuse import pad_conv_relu_ref
-    x = jnp.asarray(rng.standard_normal((1, 16, 64, 64)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((32, 16, 3, 3)) * 0.1, jnp.float32)
-    fused = jax.jit(lambda x, w: pad_conv_relu_ref(x, w))
-    rows.append(Row("micro/pad_conv_relu_us", timeit(fused, x, w),
-                    "xla-fused oracle"))
     return rows

@@ -53,8 +53,7 @@ N_HEADS = 16            # gpt2_block_fn scales scores by 1/sqrt(D // 16)
 # Switches that change which kernels run or how the gate prices them;
 # routing here must come from committed code alone.
 REFUSED_ENV = ("CODO_PALLAS_INTERPRET", "CODO_FORCE_PALLAS",
-               "CODO_DISABLE_PALLAS", "CODO_BACKEND", "CODO_TUNING_DB",
-               "CODO_ROUTING_CALIBRATION")
+               "CODO_DISABLE_PALLAS", "CODO_BACKEND")
 
 # Tolerances, each with its reason (printed beside every comparison).
 F32_TOL = dict(rtol=1e-4, atol=1e-4)
@@ -72,7 +71,6 @@ DECISION_WHY = {
     "declined": "factory declined at build time: resident operands exceed "
                 "the VMEM budget",
     "predicted-loss": "cost gate predicted the kernel no faster",
-    "tuned-generic": "tuning database measured generic faster",
     "sharded": "a collective lands inside the chain",
 }
 
@@ -117,12 +115,8 @@ def import_repro():
         raise SmokeError(f"the repro package is not next to this script "
                          f"({src}): {e}") from e
     from repro.core.cache import enable_jax_cache
-    from repro.core.tuning import default_tuning_db
     print(f"jax compilation cache: {enable_jax_cache()}")
-    entries = len(default_tuning_db().entries)
-    check(entries == 0, f"tuning database has {entries} entries")
-    print("codo compile cache: off (cache=None: every pass runs); "
-          "tuning database: empty")
+    print("codo compile cache: off (cache=None: every pass runs)")
 
 
 # --------------------------------------------------------------------------

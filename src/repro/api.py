@@ -250,29 +250,11 @@ class CompiledProgram:
                 np.asarray(got[k]), np.asarray(want[k]), rtol=rtol, atol=atol,
                 err_msg=f"output {k} diverged after lowering")
 
-    # ---- autotuning ------------------------------------------------------
-    def autotune(self, *, repeats: int = 5, warmup: int = 2, seed: int = 0,
-                 save_path: str | None = None) -> list:
-        """Measure routed-vs-generic for every pattern-matched chain of
-        this design (sweeping each kernel's tile candidates) and persist
-        the winners in the process tuning database — subsequent
-        :meth:`lower`/calls route on measurement instead of prediction
-        (the tuning-DB digest is in the lowering memo key, so the switch
-        is automatic).  Returns the new
-        :class:`~repro.core.tuning.TuningRecord`\\ s."""
-        from repro.core.tuning import autotune_compiled  # lazy: jax
-        records = autotune_compiled(self.compiled, repeats=repeats,
-                                    warmup=warmup, seed=seed,
-                                    save_path=save_path)
-        self._lowered = None            # re-route against the measurements
-        return records
-
     # ---- artifacts -------------------------------------------------------
     def export(self, path: str | None = None, *,
                weights: "bool | dict | None" = None, sidecar: bool = False):
         """Write (or return) the versioned JSON artifact of this design
-        (docs/artifact_format.md).  Tuning-database entries matching the
-        design's chains travel in the v1.2 ``tuning`` section.
+        (docs/artifact_format.md).
 
         ``weights=True`` embeds every weight buffer's concrete array —
         bound values first, the deterministic initializer for the rest —
@@ -458,14 +440,6 @@ class CompiledTrainStep:
                 err_msg=f"grad {w} diverged from eager jax.grad")
 
     # ---- tooling ---------------------------------------------------------
-    def autotune(self, **kw) -> list:
-        """Autotune all three phases' routed chains (see
-        :meth:`CompiledProgram.autotune`)."""
-        records = []
-        for p in (self.forward, self.backward, self.update):
-            records += p.autotune(**kw)
-        return records
-
     def provenance(self) -> dict:
         if self._provenance is not None:
             return dict(self._provenance)
@@ -528,7 +502,7 @@ def _compile_train_step(source: DataflowGraph, *, options=None, cache=_UNSET,
 
 def compile(fn: Callable | DataflowGraph, *specs,  # noqa: A001 — the API name
             options: CodoOptions | None = None, name: str | None = None,
-            cache=_UNSET, autotune: bool = False, mesh=None,
+            cache=_UNSET, mesh=None,
             sharding_strategy: str = "auto", grad: bool = False,
             opt=None, wrt=None,
             **codo_kwargs) -> CompiledProgram:
@@ -538,11 +512,8 @@ def compile(fn: Callable | DataflowGraph, *specs,  # noqa: A001 — the API name
     ``fn`` may also be a ready :class:`DataflowGraph` (then ``specs`` must
     be empty) — the escape hatch for hand-built graphs.  ``options``
     defaults to the full opt5 pipeline; ``cache=None`` disables
-    memoization for this call.  ``autotune=True`` additionally measures
-    routed-vs-generic for every pattern-matched chain right after the
-    compile (see :meth:`CompiledProgram.autotune`) so the program routes
-    on measurement instead of the cost model's prediction.  Extra keyword
-    arguments are forwarded to :func:`~repro.core.compiler.codo_opt`.
+    memoization for this call.  Extra keyword arguments are forwarded to
+    :func:`~repro.core.compiler.codo_opt`.
 
     ``mesh`` (a jax ``Mesh`` or a
     :class:`~repro.distributed.plan.MeshSpec`) makes the result a
@@ -577,12 +548,9 @@ def compile(fn: Callable | DataflowGraph, *specs,  # noqa: A001 — the API name
         if mesh is not None:
             raise TraceError("grad=True does not compose with mesh= yet — "
                              "shard the phases individually")
-        step = _compile_train_step(source, options=options, cache=cache,
+        return _compile_train_step(source, options=options, cache=cache,
                                    opt=opt, wrt=wrt, origin=origin,
                                    **codo_kwargs)
-        if autotune:
-            step.autotune()
-        return step
     if opt is not None or wrt is not None:
         raise TraceError("opt=/wrt= only apply with grad=True")
     compiled = codo_opt(source, options, cache=cache, **codo_kwargs)
@@ -590,8 +558,6 @@ def compile(fn: Callable | DataflowGraph, *specs,  # noqa: A001 — the API name
     program.origin = origin
     if mesh is not None:
         program.shard(mesh, sharding_strategy)
-    if autotune:
-        program.autotune()
     return program
 
 

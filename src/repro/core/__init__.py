@@ -26,8 +26,7 @@ from .graph import (FIFO, PINGPONG, Access, Buffer, DataflowGraph, Loop, Task,
                     matmul_task, pad_task, pool_task, reduce_task, retarget_fn)
 from .lowering import (LOWER_CACHE_STATS, FusionGroup, LoweredProgram,
                        clear_lower_cache, fusion_groups, lower,
-                       lower_artifact, register_group_kernel,
-                       verify_lowering, verify_routing)
+                       lower_artifact, verify_lowering, verify_routing)
 from .offchip import TransferPlan, host_manifest, plan_offchip
 from .ops import (OpSpec, UnknownOpError, materialize, op_impl, register_op,
                   registered_ops)
@@ -69,7 +68,7 @@ __all__ = [
     "materialize", "matmul_task", "op_impl", "pad_task",
     "pallas_disabled", "parallel_safety", "plan_offchip", "pool_task",
     "reduce_task",
-    "register_group_kernel", "register_kernel_pattern", "register_op",
+    "register_kernel_pattern", "register_op",
     "registered_ops", "registered_patterns", "retarget_fn", "route_plan",
     "sequential_latency", "task_cost", "trace", "trace_io",
     "validate_artifact",

@@ -104,13 +104,10 @@ SCHEMA_VERSION = "1.5"
 #      execution never reaches ``weight_init``.  Older readers ignore the
 #      section (unknown-field policy) and this reader accepts v1.0–v1.2
 #      documents without it.
-# 1.2  `tuning`: measured autotune results for the design's routed chains
-#      — `{"entries": [TuningRecord dicts]}` keyed on chain structural
-#      signature + backend + hw name (see repro.core.tuning).  Importers
-#      merge the entries into the process tuning database so measured
-#      routing decisions travel with the artifact; older readers ignore
-#      the section (unknown-field policy) and this reader accepts v1.0/
-#      v1.1 documents without it.  `diagnostics.group_kernels` values
+# 1.2  `tuning`: measured routing records for the design's chains, written
+#      by earlier exporters.  Kernel choice comes from the cost gate alone:
+#      this reader accepts the optional field and ignores it with a note,
+#      and exporters no longer write it.  `diagnostics.group_kernels` values
 #      became per-group entry dicts (kernel + cost-gate decision +
 #      predicted cycles); bare v1.1 strings are still read.
 # 1.1  `fusion.kernels`: per-group kernel-routing decision ("xla-fused" or
@@ -301,7 +298,6 @@ def export_artifact(compiled: CompiledDataflow,
 
     impl = compiled.buffer_plan.impl if compiled.buffer_plan else {}
     groups = _fifo_groups(g, impl)
-    tuning = _design_tuning(g, impl, groups)
     doc: dict[str, Any] = {
         "schema_version": SCHEMA_VERSION,
         "generator": GENERATOR,
@@ -328,7 +324,6 @@ def export_artifact(compiled: CompiledDataflow,
         },
         "diagnostics": (compiled.diagnostics.to_dict()
                         if compiled.diagnostics else None),
-        "tuning": tuning,
         "integrity": {"structural_hash": g.structural_hash()},
     }
     if weights is not None:
@@ -340,26 +335,6 @@ def export_artifact(compiled: CompiledDataflow,
     if path is not None:
         Path(path).write_text(dumps(doc))
     return doc
-
-
-def _design_tuning(graph: DataflowGraph, impl: dict[str, str],
-                   groups: list[list[str]]) -> dict | None:
-    """The v1.2 ``tuning`` payload: every process tuning-database entry
-    whose chain signature occurs in this design (all backends/hardware —
-    the importer's routing picks the entries for its own environment).
-    ``None`` when no measured entries apply."""
-    from .tuning import chain_signature, default_tuning_db
-    ensure_kernel_patterns()
-    sigs = set()
-    for names in groups:
-        if len(names) < 2:
-            continue
-        for _pat, tasks in match_group(graph, names, impl):
-            sigs.add(chain_signature(graph, tasks))
-    entries = [r.to_dict() for k, r in
-               sorted(default_tuning_db().entries.items())
-               if r.signature in sigs]
-    return {"entries": entries} if entries else None
 
 
 def dumps(doc: dict) -> str:
@@ -387,7 +362,7 @@ _TOP_FIELDS = {
     "fusion": ((dict, type(None)), False),
     "cost": ((dict, type(None)), False),
     "diagnostics": ((dict, type(None)), False),
-    # v1.2: measured autotune entries for the design's routed chains.
+    # v1.2: measured routing records; read and ignored (see above).
     "tuning": ((dict, type(None)), False),
     # v1.3: bound weight payloads (embedded base64 or .npz sidecar).
     "weights": ((dict, type(None)), False),
@@ -518,24 +493,6 @@ _COST_FIELDS = {
     "fifo_fraction": (_NUM, False),
     "bottleneck": (_OPT_STR, False),
     "units": (_NUM + (type(None),), False),
-}
-
-_TUNING_FIELDS = {
-    "entries": ((list,), True),
-}
-
-# Per-entry fields of the v1.2 `tuning.entries` records (TuningRecord).
-_TUNING_ENTRY_FIELDS = {
-    "signature": ((str,), True),
-    "backend": ((str,), True),
-    "hw": ((str,), True),
-    "pattern": ((str,), False),
-    "choice": ((str,), False),
-    "tile": ((dict, type(None)), False),
-    "routed_ms": (_NUM, False),
-    "generic_ms": (_NUM, False),
-    "workload": ((str,), False),
-    "tasks": ((list,), False),
 }
 
 # v1.3 `weights` section and its per-array entries.
@@ -701,16 +658,10 @@ def validate_artifact(doc: Any) -> list[str]:
                           f"{len(groups)} groups (must align)")
     if isinstance(doc.get("cost"), dict):
         _check_fields(doc["cost"], "cost", _COST_FIELDS, errors, notes)
-    tuning = doc.get("tuning")
-    if isinstance(tuning, dict):
-        _check_fields(tuning, "tuning", _TUNING_FIELDS, errors, notes)
-        for i, entry in enumerate(tuning.get("entries") or ()):
-            if not isinstance(entry, dict):
-                errors.append(f"tuning.entries[{i}]: expected dict, got "
-                              f"{type(entry).__name__}")
-                continue
-            _check_fields(entry, f"tuning.entries[{i}]",
-                          _TUNING_ENTRY_FIELDS, errors, notes)
+    if doc.get("tuning"):
+        notes.append("tuning: measured routing records from an older "
+                     "exporter (ignored — the cost gate decides every "
+                     "kernel)")
     wts = doc.get("weights")
     if isinstance(wts, dict):
         _check_fields(wts, "weights", _WEIGHTS_FIELDS, errors, notes)
@@ -956,21 +907,6 @@ def import_artifact(source: str | Path | dict, *,
                       "is re-derived against the local kernel registry at "
                       "lower() time")
 
-    # v1.2 tuning entries: merge the measured routing decisions into the
-    # process tuning database so this design (and any same-shaped chain)
-    # routes on measurement here too.  The DB digest is part of the
-    # lowering memo key, so the merge invalidates stale lowerings.
-    tuning = doc.get("tuning") or {}
-    if tuning.get("entries"):
-        from .tuning import TuningRecord, default_tuning_db
-        try:
-            default_tuning_db().merge(
-                TuningRecord.from_dict(e) for e in tuning["entries"])
-        except (KeyError, TypeError, ValueError) as e:
-            raise ArtifactError(
-                f"tuning does not reconstruct ({type(e).__name__}: {e}) — "
-                "corrupted values?") from e
-
     # v1.4 sharding section: reconstruct the pure-data plan (its stored
     # digest is re-checked by from_dict) and attach it to the design —
     # ``codo.load`` turns it back into a multi-device program.  No device
@@ -1038,7 +974,7 @@ def export_train_step_artifact(phases: dict, train: dict,
     ``phases`` maps ``forward``/``backward``/``update`` to their
     :class:`CompiledDataflow`; each is exported as a full artifact under
     ``phases.<name>``, so every per-phase guarantee (integrity hash,
-    fusion cross-check, tuning merge) holds phase by phase on import.
+    fusion cross-check) holds phase by phase on import.
     ``train`` is the autodiff linking section — loss buffer, seed
     cotangents, residual buffers shared forward→backward, per-parameter
     gradient buffers, and the optimizer attrs.  ``weights`` embeds the
@@ -1239,10 +1175,9 @@ def diff_artifacts(a: str | Path | dict, b: str | Path | dict) -> list[str]:
 
     An empty list means the artifacts agree on everything the compiler
     decided: schema version, graph structure (structural hash + counts),
-    fusion grouping and kernel routing, autotuning entries, and the v1.4
-    ``sharding`` section.  Cosmetic fields (generator string, measured
-    milliseconds inside tuning records) are ignored so re-exports of the
-    same design diff clean.
+    fusion grouping and kernel routing, and the v1.4 ``sharding``
+    section.  Cosmetic fields (the generator string) are ignored so
+    re-exports of the same design diff clean.
 
     With v1.5 ``provenance`` on both sides, a graph difference is
     classified: *same source, different pipeline* (equal pre-pass source
@@ -1301,21 +1236,6 @@ def diff_artifacts(a: str | Path | dict, b: str | Path | dict) -> list[str]:
     ka, kb = list(fa.get("kernels") or ()), list(fb.get("kernels") or ())
     if ka != kb:
         out.append(f"fusion.kernels: {ka} != {kb}")
-
-    def _tuning(doc):
-        entries = (doc.get("tuning") or {}).get("entries") or ()
-        return {f"{e.get('signature')}:{e.get('backend')}:{e.get('hw')}":
-                (e.get("choice"), json.dumps(e.get("tile"), sort_keys=True))
-                for e in entries}
-
-    ta, tb = _tuning(da), _tuning(db)
-    for key in sorted(set(ta) - set(tb)):
-        out.append(f"tuning[{key}]: only in first")
-    for key in sorted(set(tb) - set(ta)):
-        out.append(f"tuning[{key}]: only in second")
-    for key in sorted(set(ta) & set(tb)):
-        if ta[key] != tb[key]:
-            out.append(f"tuning[{key}]: choice/tile {ta[key]} != {tb[key]}")
 
     sa, sb = da.get("sharding"), db.get("sharding")
     if (sa is None) != (sb is None):

@@ -256,43 +256,6 @@ class CompileCache:
             with self._lock:
                 self.stats.disk_errors += 1
 
-    # ---- tuning-database tier (ISSUE 6) ----------------------------------
-    @property
-    def tuning_path(self) -> Path | None:
-        """Where the disk tier keeps measured autotune results
-        (``tuning.json``, a :class:`repro.core.tuning.TuningDB` document);
-        ``None`` for memory-only caches."""
-        return self.disk_dir / "tuning.json" if self.disk_dir else None
-
-    def load_tuning_db(self, merge_into_default: bool = True) -> int:
-        """Merge the disk tier's persisted tuning entries into the process
-        tuning database (so cached measured routing decisions survive
-        process restarts like cached compiles do).  Returns the number of
-        entries merged; 0 when there is nothing to load."""
-        path = self.tuning_path
-        if path is None or not path.exists():
-            return 0
-        from .tuning import TuningDB, default_tuning_db
-        try:
-            loaded = TuningDB.load(path)
-        except (OSError, ValueError, KeyError):
-            with self._lock:
-                self.stats.disk_errors += 1
-            return 0
-        if merge_into_default:
-            return default_tuning_db().merge(loaded.entries.values())
-        return len(loaded)
-
-    def save_tuning_db(self, db=None) -> Path | None:
-        """Persist ``db`` (the process default when ``None``) to the disk
-        tier's ``tuning.json``.  No-op for memory-only caches."""
-        path = self.tuning_path
-        if path is None:
-            return None
-        from .tuning import default_tuning_db
-        (db if db is not None else default_tuning_db()).save(path)
-        return path
-
     # ---- maintenance -----------------------------------------------------
     def __len__(self) -> int:
         with self._lock:

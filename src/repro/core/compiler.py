@@ -391,8 +391,8 @@ class BatchResult:
         return bool(self.compiled and self.compiled.cache_hit)
 
     def derived(self) -> str:
-        """The per-cell metrics string shared by the CLI CSV and
-        benchmarks.tables.batch_grid_rows."""
+        """The per-cell metrics string of the CLI's ``--csv`` rows and
+        the paper tables' batch-grid rows."""
         if not self.ok:
             return self.error
         c = self.compiled
@@ -613,22 +613,18 @@ def _register_kernel_patterns() -> None:
 def routing_table(results) -> str:
     """The ``--profile`` kernel-routing table: per grid cell, how many
     fusion groups the cost gate routes to Pallas kernels, and per matched
-    chain the predicted speedup vs the measured one (when the tuning
-    database has an entry) — predicted-vs-measured at a glance.  Derived
-    structurally (:func:`repro.core.routing.route_plan`) — no lowering,
-    no jax execution."""
+    chain its decision and predicted speedup.  Derived structurally
+    (:func:`repro.core.routing.route_plan`) — no lowering, no jax
+    execution."""
     from .routing import XLA_FUSED, route_plan
-    lines = ["-- kernel routing (cost-gated; predicted vs measured) --"]
+    lines = ["-- kernel routing (cost-gated, predicted) --"]
     pattern_counts: dict[str, int] = {}
     total = routed = 0
 
     def _chain_line(c: dict, verdict: str) -> str:
         pred = (c["predicted_generic_cycles"]
                 / max(c["predicted_routed_cycles"], 1e-9))
-        measured = c.get("measured_speedup")
-        meas = f"{measured:.3f}x" if measured is not None else "--"
-        return (f"    {c['kernel']:<24s} {verdict:<16s} "
-                f"pred={pred:.3f}x meas={meas} "
+        return (f"    {c['kernel']:<24s} {verdict:<16s} pred={pred:.3f}x "
                 f"({'+'.join(c['tasks'])})")
 
     for r in results:
@@ -658,49 +654,42 @@ def routing_table(results) -> str:
     return "\n".join(lines)
 
 
-def autotune_results(results, cache=None, *, repeats: int = 5,
-                     warmup: int = 2) -> str:
-    """The ``--autotune`` verb: measure routed-vs-generic (sweeping tile
-    candidates) for every compiled cell, persist the winners in the
-    process tuning database (and the cache's disk tier when present), and
-    return a per-chain report."""
-    from .tuning import autotune_compiled, default_tuning_db
-    lines = ["-- autotune (measured routed-vs-generic per chain) --"]
-    tuned = 0
-    for r in results:
-        if not r.ok:
-            continue
-        try:
-            recs = autotune_compiled(r.compiled, repeats=repeats,
-                                     warmup=warmup)
-        except Exception as e:           # un-executable cells (stripped fns)
-            lines.append(f"  {r.config}/{r.preset}: skipped ({e})")
-            continue
-        tuned += len(recs)
-        for rec in recs:
-            tile = f" tile={rec.tile}" if rec.tile else ""
-            lines.append(
-                f"  {r.config}/{r.preset} {rec.pattern:<24s} -> "
-                f"{rec.choice:<9s} {rec.speedup:.3f}x "
-                f"(routed={rec.routed_ms:.3f}ms generic={rec.generic_ms:.3f}"
-                f"ms){tile}")
-    db = default_tuning_db()
-    where = ""
-    if cache is not None and getattr(cache, "disk_dir", None):
-        path = cache.save_tuning_db(db)
-        where = f"; persisted to {path}"
-    lines.append(f"  {tuned} chains measured; tuning DB has {len(db)} "
-                 f"entries{where}")
-    return "\n".join(lines)
-
-
 # --------------------------------------------------------------------------
 # CLI:  python -m repro.core.compiler --all --ablations
 # --------------------------------------------------------------------------
 
 
-def _fallback_grid(results) -> str:
-    return "\n".join(["config,preset,speedup,derived"] + [r.csv() for r in results])
+def format_batch_grid(results) -> str:
+    """Table VII-style text grid from ``codo_opt_batch`` results: one row
+    per config, one speedup column per preset.  Cached cells are marked
+    ``*`` (their compile time is the lookup, not a pipeline run)."""
+    presets = sorted({r.preset for r in results},
+                     key=lambda p: list(ABLATION_PRESETS).index(p)
+                     if p in ABLATION_PRESETS else 99)
+    configs = sorted({r.config for r in results})
+    by_cell = {(r.config, r.preset): r for r in results}
+
+    w = max([len(c) for c in configs] + [8])
+    head = f"{'config':<{w}s} " + " ".join(f"{p:>12s}" for p in presets) \
+        + "   fifo   compile_ms"
+    lines = [head, "-" * len(head)]
+    for cname in configs:
+        cells, fifo, ms = [], "", 0.0
+        for p in presets:
+            r = by_cell.get((cname, p))
+            if r is None or not r.ok:
+                cells.append(f"{'ERR':>12s}")
+                continue
+            mark = "*" if r.cache_hit else ""
+            cells.append(f"{r.compiled.speedup:>11.1f}{mark or 'x'}")
+            fifo = f"{r.compiled.fifo_fraction:.2f}"
+            ms += r.compiled.compile_seconds * 1e3
+        lines.append(f"{cname:<{w}s} " + " ".join(cells)
+                     + f"   {fifo:>4s}   {ms:>9.1f}")
+    lines.append("(speedup vs sequential baseline; '*' = compile-cache hit; "
+                 "fifo from the last preset column; compile_ms = row total "
+                 "across preset columns)")
+    return "\n".join(lines)
 
 
 def main(argv=None) -> int:
@@ -757,11 +746,6 @@ def main(argv=None) -> int:
     ap.add_argument("--profile", action="store_true",
                     help="print the per-pass timing table aggregated from "
                          "CompileDiagnostics")
-    ap.add_argument("--autotune", action="store_true",
-                    help="after the grid, measure routed-vs-generic per "
-                         "matched chain (sweeping each kernel's tile "
-                         "candidates), persist the winners in the tuning "
-                         "database, and print the measured table")
     ap.add_argument("--export", default="", metavar="DIR",
                     help="export every compiled cell as a versioned JSON "
                          "artifact to DIR (docs/artifact_format.md)")
@@ -811,8 +795,6 @@ def main(argv=None) -> int:
         cache = CompileCache(disk_dir=args.cache_dir or None)
         if args.clear_cache:
             cache.clear(disk=True)
-        # Measured routing decisions persist next to the compiles.
-        cache.load_tuning_db()
 
     budgets = None
     if args.pass_budget or args.enforce_budgets:
@@ -843,7 +825,7 @@ def main(argv=None) -> int:
             budgets[pname] = (dims["seconds"]
                               if set(dims) == {"seconds"} else dims)
 
-    if args.profile or args.export or args.autotune:
+    if args.profile or args.export:
         _register_kernel_patterns()     # routing verbs see the real registry
     jobs = ablation_jobs(workloads, presets, budget_units=args.budget,
                          pass_budgets=budgets)
@@ -852,16 +834,7 @@ def main(argv=None) -> int:
                              executor=args.executor)
     wall = time.perf_counter() - t0
 
-    # Table VII-style report lives with the other paper tables.
-    try:
-        repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-        if repo_root not in sys.path:
-            sys.path.insert(0, repo_root)
-        from benchmarks.tables import format_batch_grid
-        print(format_batch_grid(results))
-    except ImportError:
-        print(_fallback_grid(results))
+    print(format_batch_grid(results))
 
     hits = sum(1 for r in results if r.cache_hit)
     errors = [r for r in results if not r.ok]
@@ -879,9 +852,6 @@ def main(argv=None) -> int:
             print(cache.stats.summary())
     for r in errors:
         print(f"ERROR {r.config}/{r.preset}: {r.error}", file=sys.stderr)
-    if args.autotune:
-        print()
-        print(autotune_results(results, cache))
     if args.profile:
         print()
         print(profile_table(r.compiled.diagnostics for r in results if r.ok))
@@ -921,7 +891,8 @@ def main(argv=None) -> int:
         print(f"exported {exported}/{len(results)} artifacts to {args.export}")
     if args.csv:
         with open(args.csv, "w") as f:
-            f.write(_fallback_grid(results) + "\n")
+            f.write("\n".join(["config,preset,speedup,derived"]
+                              + [r.csv() for r in results]) + "\n")
     return 1 if errors else 0
 
 

@@ -287,24 +287,23 @@ def sequential_latency(graph: DataflowGraph, hw: HwParams = V5E) -> GraphCost:
 # --------------------------------------------------------------------------
 # Routing predictor (ISSUE 6): routed-kernel vs generic-XLA latency per
 # pattern-matched chain.  Same II/trip-count arithmetic as above, plus a
-# small per-backend parameter vector calibrated from the measured routing
-# bench (results/bench/routing_groups.json).
+# small per-backend vector of committed constants.
 # --------------------------------------------------------------------------
 
 
 @dataclass(frozen=True)
 class RoutingCostParams:
-    """Calibration constants the routing gate combines with structural
+    """Committed constants the routing gate combines with structural
     :func:`task_cost` cycles.
 
-    ``efficiency[pattern]`` is the kernel's measured throughput relative
-    to the generic path for same-shaped work (geomean of bench speedups —
-    1.0 means parity).  ``generic_spill``/``stream_overlap`` model what
-    the kernel *structurally* changes: on TPU the generic path bounces
+    ``efficiency[pattern]`` is the kernel's throughput relative to the
+    generic path for same-shaped work (1.0 means parity).
+    ``generic_spill``/``stream_overlap`` model what the kernel
+    *structurally* changes: on TPU the generic path bounces
     chain interiors through HBM (spill=1) while the kernel pipelines
     stages (overlap=1); on CPU hosts both sides are one XLA:CPU fusion,
-    so neither effect materializes and only the calibrated efficiency and
-    the per-kernel dispatch overhead separate them.
+    so neither effect materializes and only the efficiency and the
+    per-kernel dispatch overhead separate them.
     """
 
     backend: str = "cpu"
@@ -326,10 +325,11 @@ class RoutingCostParams:
         return hashlib.sha256(repr(canon).encode()).hexdigest()[:16]
 
 
-# Defaults calibrated from the recorded CPU routing bench (nightly
-# routing_groups.json; see calibrate_routing_params): conv chains sit at
-# ~0.99x parity, mmchains at parity, and the softmaxmm tail measures 0.97x
-# — below the slack band, so the gate routes it to generic XLA on CPU.
+# Committed constants that keep kernels generic on a CPU host, where a
+# routed chain runs its jnp reference and can only tie or lose: conv
+# chains sit just under parity, mmchains at parity, and the softmaxmm tail
+# at 0.97x — below the slack band, so the gate leaves it to generic XLA.
+# They are not fit from any file.
 _CPU_PARAMS = RoutingCostParams(
     backend="cpu",
     efficiency=(("streamfuse.conv", 0.99), ("streamfuse.mmchain", 1.0),
@@ -394,60 +394,13 @@ def device_hw(default: HwParams = V5E) -> HwParams:
             f"to costmodel.HW_BY_DEVICE_KIND)") from None
 
 
-def calibrate_routing_params(doc: dict,
-                             base: RoutingCostParams | None = None,
-                             ) -> RoutingCostParams:
-    """Fit per-pattern efficiency from a ``routing_groups.json`` document:
-    the geomean of each pattern's measured ``speedup`` (xla_ms/pallas_ms),
-    clamped to a sane band.  Everything else comes from ``base`` (defaults
-    for the document's backend)."""
-    from dataclasses import replace
-    backend = str(doc.get("backend", "cpu"))
-    if base is None:
-        base = DEFAULT_ROUTING_PARAMS.get(
-            backend, replace(_CPU_PARAMS, backend=backend))
-    logs: dict[str, list[float]] = {}
-    for r in doc.get("records", ()):
-        s = float(r.get("speedup", 0.0) or 0.0)
-        if s > 0:
-            logs.setdefault(str(r.get("kernel", "?")), []).append(np.log(s))
-    eff = dict(base.efficiency)
-    for pat, ls in logs.items():
-        eff[pat] = float(np.clip(np.exp(np.mean(ls)), 0.5, 2.0))
-    return replace(base, backend=backend,
-                   efficiency=tuple(sorted(eff.items())))
-
-
-_CALIBRATION_CACHE: dict[str, RoutingCostParams] = {}
-
-
 def routing_params(backend: str | None = None) -> RoutingCostParams:
-    """Active gate parameters: defaults for ``backend`` (detected when
-    ``None``), recalibrated from the ``CODO_ROUTING_CALIBRATION`` bench
-    JSON when that points at a readable document for the same backend."""
-    import json
-    import os
+    """The gate's committed constants for ``backend`` (detected when
+    ``None``); a backend without a table prices like the CPU."""
     from dataclasses import replace
     backend = backend or routing_backend()
-    base = DEFAULT_ROUTING_PARAMS.get(
+    return DEFAULT_ROUTING_PARAMS.get(
         backend, replace(_CPU_PARAMS, backend=backend))
-    path = os.environ.get("CODO_ROUTING_CALIBRATION", "").strip()
-    if not path:
-        return base
-    key = f"{path}:{backend}"
-    hit = _CALIBRATION_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        doc = json.loads(open(path).read())
-    except (OSError, ValueError):
-        return base
-    if str(doc.get("backend", backend)) != backend:
-        params = base
-    else:
-        params = calibrate_routing_params(doc, base)
-    _CALIBRATION_CACHE[key] = params
-    return params
 
 
 @dataclass(frozen=True)
@@ -472,7 +425,7 @@ def estimate_chain(graph: DataflowGraph, tasks: list[Task],
 
     generic = sum of stage latencies + the interior HBM round-trips the
     un-routed path materializes (backend-scaled); routed = the pipelined
-    stage latencies at the kernel's calibrated efficiency plus a fixed
+    stage latencies at the kernel's efficiency constant plus a fixed
     dispatch overhead.  The gate routes iff routed is predicted no slower
     than generic beyond the noise band (``params.slack``).
     """

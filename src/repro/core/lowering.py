@@ -47,28 +47,9 @@ from .compiler import CompiledDataflow
 from .costmodel import device_hw
 from .graph import FIFO, DataflowGraph, GraphError, Task, task_region
 from .ops import registry_epoch as _ops_epoch
-from .routing import (XLA_FUSED, KernelPattern, RoutedKernel,
-                      ensure_kernel_patterns, pallas_interpret_forced,
-                      register_kernel_pattern, route_groups,
+from .routing import (XLA_FUSED, RoutedKernel, ensure_kernel_patterns,
+                      pallas_interpret_forced, route_groups,
                       routing_state_key)
-
-def register_group_kernel(pattern: tuple[str, ...],
-                          factory: Callable[..., Callable]) -> None:
-    """Legacy exact-op registration (pre-routing API): ``pattern`` is the
-    full op tuple of a group and ``factory(graph, group)`` builds the
-    step.  Kept as a shim over :func:`repro.core.routing.
-    register_kernel_pattern`; new kernels should register a
-    :class:`~repro.core.routing.KernelPattern` directly."""
-    def adapter(graph, group, tasks):
-        # Old factories index group.tasks positionally, assuming the match
-        # covers the whole group; hand them a group view of just the chain.
-        chain = FusionGroup(group.gid, [t.name for t in tasks],
-                            tuple(t.op for t in tasks))
-        return factory(graph, chain)
-
-    register_kernel_pattern(KernelPattern(
-        name="+".join(pattern), pattern=tuple(pattern), factory=adapter,
-        description="legacy exact-op registration"))
 
 
 @dataclass
@@ -168,7 +149,7 @@ _COUNT_LOCK = threading.Lock()
 # tasks it runs as triangular matmuls (``tri_cumsums``, likewise); and the
 # candidate chains the gate declined (``declined``, set at lowering): a
 # Counter keyed by (pattern, reason), the reason ``infeasible`` (the
-# pattern's guard), ``cost`` (the cost model or tuning database), ``vmem``
+# pattern's guard), ``cost`` (the cost model), ``vmem``
 # (the factory: a grid step's blocks exceed VMEM) or ``sharded``.
 LOWER_CACHE_STATS = {"hits": 0, "misses": 0,
                      "dispatches": Counter(), "traces": Counter(),
@@ -220,12 +201,8 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
             for route in g.routes:
                 pat = pats.get(route.kernel)
                 tasks = [graph.task(n) for n in route.tasks]
-                if pat is None:
-                    step = None
-                elif route.tile is not None:    # tuned blocking wins
-                    step = pat.factory(graph, g, tasks, tile=route.tile)
-                else:
-                    step = pat.factory(graph, g, tasks)
+                step = (pat.factory(graph, g, tasks) if pat is not None
+                        else None)
                 if step is None:        # factory declined at build time
                     continue
                 built.append(route)
@@ -265,8 +242,8 @@ def _build_steps(graph: DataflowGraph, groups: list[FusionGroup],
 
 
 # Why a candidate chain stayed generic, by its route's decision.
-_DECLINE_REASON = {"predicted-loss": "cost", "tuned-generic": "cost",
-                   "declined": "vmem", "sharded": "sharded"}
+_DECLINE_REASON = {"predicted-loss": "cost", "declined": "vmem",
+                   "sharded": "sharded"}
 
 
 def declined_chains(groups: list[FusionGroup],
@@ -525,10 +502,9 @@ def lower(compiled: CompiledDataflow, jit: bool = True,
             "with an in-memory cache / cache=None before lowering.")
     # Key covers fusion decisions (via the structural hash), the flags, and
     # every routing-relevant switch (routing_state_key: the disable/force
-    # escape hatches, the registry epoch, the priced backend, the
-    # calibration digest, and the tuning-DB digest) plus the op-impl
-    # registry epoch — flipping any of them must never serve a stale
-    # program.
+    # escape hatches, the registry epoch, the priced backend and its gate
+    # constants' digest) plus the op-impl registry epoch — flipping any of
+    # them must never serve a stale program.
     key = (graph.structural_hash(), bool(jit), bool(use_registered_kernels),
            pallas_interpret_forced(), *routing_state_key(), _ops_epoch(),
            sharding.digest() if sharding is not None else "")

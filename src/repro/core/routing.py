@@ -43,10 +43,10 @@ Cost gate (ISSUE 6)
 A structural match is necessary but not sufficient: each matched chain is
 priced both ways by the cost model (:func:`repro.core.costmodel.
 estimate_chain`) and routed only on predicted win — small chains whose
-dispatch overhead would dominate, and patterns the calibration says lose
-on this backend (the CPU softmaxmm tail), fall back to generic XLA.  A
-measured :class:`~repro.core.tuning.TuningDB` verdict beats the
-predictor when one exists for the chain's structural signature.
+dispatch overhead would dominate, and patterns the committed constants
+say lose on this backend (the CPU softmaxmm tail), fall back to generic
+XLA.  The gate is the only place a kernel is decided: its inputs are the
+graph, the hardware model and committed constants, never a measurement.
 
 Escape hatches
 --------------
@@ -54,17 +54,16 @@ Escape hatches
 ``CODO_DISABLE_PALLAS=1`` disables all routing — every group falls back
 to ``xla-fused``.  ``CODO_FORCE_PALLAS=1`` routes every structural match
 regardless of the gate's prediction (disable wins over force).  Both
-flags — plus the registry epoch, the backend, the calibration digest,
-and the tuning-DB digest — enter the lowering memo key via
-:func:`routing_state_key`, so toggling any of them never serves a stale
-program.
+flags — plus the registry epoch, the backend and the gate constants'
+digest — enter the lowering memo key via :func:`routing_state_key`, so
+toggling any of them never serves a stale program.
 """
 
 from __future__ import annotations
 
 import os
 import warnings
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Sequence
 
 from .graph import DataflowGraph, Task
@@ -118,10 +117,8 @@ class KernelPattern:
 
     ``factory(graph, group, tasks)`` returns an ``env -> {out: array}``
     callable (it may import jax lazily); returning ``None`` declines the
-    match at build time (treated like an infeasible guard).  Patterns
-    whose kernels take tile/block parameters declare a ``tiles(graph,
-    tasks)`` candidate enumerator and accept the winning candidate as a
-    ``tile=`` keyword on the factory (``None`` = kernel default).
+    match at build time (treated like an infeasible guard).  Each factory
+    picks its own blocks from the chain's shapes.
     """
 
     name: str
@@ -129,7 +126,6 @@ class KernelPattern:
     factory: Callable[[DataflowGraph, Any, list[Task]], Callable | None]
     feasible: Callable[[DataflowGraph, list[Task]], bool] | None = None
     description: str = ""
-    tiles: Callable[[DataflowGraph, list[Task]], list[dict | None]] | None = None
     # Recurrence kernels (rglru/ssd chunked scans) replace ONE task whose
     # generic form is sequential — for them a single-task "chain" is the
     # whole point, so they opt out of the >=2-task floor below.
@@ -147,41 +143,33 @@ class KernelPattern:
 @dataclass
 class RoutedKernel:
     """One gate decision inside a fusion group: a structurally matched
-    chain, the cost model's verdict on it, and — when the decision came
-    from the tuning database — the measured numbers.  Chains whose
-    ``decision`` is in :data:`ROUTED_DECISIONS` execute as the registered
-    kernel; the rest stay on the generic path (recorded for the
-    diagnostics/--profile predicted-vs-measured table)."""
+    chain and the cost model's verdict on it.  Chains whose ``decision``
+    is in :data:`ROUTED_DECISIONS` execute as the registered kernel; the
+    rest stay on the generic path (recorded for the diagnostics and the
+    --profile routing table)."""
 
     kernel: str                  # KernelPattern.name
     tasks: list[str]             # matched chain, dataflow order
     decision: str = "predicted-win"
     predicted_routed_cycles: float = 0.0
     predicted_generic_cycles: float = 0.0
-    tile: dict | None = None     # tuned blocking (None = kernel default)
-    measured_speedup: float | None = None   # generic/routed, tuning DB
 
     @property
     def routed(self) -> bool:
         return self.decision in ROUTED_DECISIONS
 
     def to_dict(self) -> dict:
-        out = {"kernel": self.kernel, "tasks": list(self.tasks),
-               "decision": self.decision,
-               "predicted_routed_cycles": round(
-                   self.predicted_routed_cycles, 1),
-               "predicted_generic_cycles": round(
-                   self.predicted_generic_cycles, 1)}
-        if self.tile is not None:
-            out["tile"] = dict(self.tile)
-        if self.measured_speedup is not None:
-            out["measured_speedup"] = round(self.measured_speedup, 4)
-        return out
+        return {"kernel": self.kernel, "tasks": list(self.tasks),
+                "decision": self.decision,
+                "predicted_routed_cycles": round(
+                    self.predicted_routed_cycles, 1),
+                "predicted_generic_cycles": round(
+                    self.predicted_generic_cycles, 1)}
 
 
-# Decisions that put a chain on the kernel path; everything else
-# ("predicted-loss", "tuned-generic") stays generic.
-ROUTED_DECISIONS = frozenset({"predicted-win", "forced", "tuned"})
+# Decisions that put a chain on the kernel path; "predicted-loss" stays
+# generic.
+ROUTED_DECISIONS = frozenset({"predicted-win", "forced"})
 
 
 # --------------------------------------------------------------------------
@@ -366,32 +354,21 @@ def match_group(graph: DataflowGraph, group_tasks: Sequence[str],
 
 
 def decide_route(graph: DataflowGraph, tasks: list[Task],
-                 pattern: KernelPattern, *, hw=None, params=None,
-                 db=None) -> RoutedKernel:
+                 pattern: KernelPattern, *, hw=None,
+                 params=None) -> RoutedKernel:
     """The cost gate for one structurally matched chain.
 
-    Precedence: a measured :class:`~repro.core.tuning.TuningDB` entry for
-    the chain's signature on this backend/hardware (``tuned`` /
-    ``tuned-generic``), then the ``CODO_FORCE_PALLAS`` override
-    (``forced``), then the predictor (``predicted-win`` /
-    ``predicted-loss``).  The predicted cycles are recorded on the result
-    either way.
+    Precedence: the ``CODO_FORCE_PALLAS`` override (``forced``), then the
+    predictor (``predicted-win`` / ``predicted-loss``).  The predicted
+    cycles are recorded on the result either way.
     """
-    from .costmodel import V5E, estimate_chain, routing_backend
-    from .tuning import chain_signature, default_tuning_db
+    from .costmodel import V5E, estimate_chain
     hw = hw if hw is not None else V5E
     est = estimate_chain(graph, tasks, pattern.name, hw, params)
     route = RoutedKernel(pattern.name, [t.name for t in tasks],
                          predicted_routed_cycles=est.routed_cycles,
                          predicted_generic_cycles=est.generic_cycles)
-    if db is None:
-        db = default_tuning_db()
-    rec = db.lookup(chain_signature(graph, tasks), routing_backend(), hw.name)
-    if rec is not None:
-        route.decision = "tuned" if rec.choice == "pallas" else "tuned-generic"
-        route.tile = dict(rec.tile) if rec.tile else None
-        route.measured_speedup = rec.speedup
-    elif pallas_forced():
+    if pallas_forced():
         route.decision = "forced"
     else:
         route.decision = "predicted-win" if est.win else "predicted-loss"
@@ -400,7 +377,7 @@ def decide_route(graph: DataflowGraph, tasks: list[Task],
 
 def route_groups(graph: DataflowGraph, groups, impl: dict[str, str], *,
                  enabled: bool | None = None, hw=None, params=None,
-                 db=None, declined: list | None = None) -> None:
+                 declined: list | None = None) -> None:
     """Annotate each :class:`~repro.core.lowering.FusionGroup` in
     ``groups`` with its routing decision: cost-gate-accepted chains in
     ``routes``, gate-rejected structural matches in ``rejected``, and the
@@ -425,7 +402,7 @@ def route_groups(graph: DataflowGraph, groups, impl: dict[str, str], *,
             for pat, tasks in match_group(graph, g.tasks, impl,
                                           declined=declined):
                 route = decide_route(graph, tasks, pat, hw=hw,
-                                     params=params, db=db)
+                                     params=params)
                 (g.routes if route.routed else g.rejected).append(route)
                 if route.routed:
                     chained.update(route.tasks)
@@ -443,12 +420,12 @@ def route_groups(graph: DataflowGraph, groups, impl: dict[str, str], *,
 
 
 def route_plan(graph: DataflowGraph, impl: dict[str, str], *,
-               enabled: bool | None = None, hw=None, params=None,
-               db=None) -> list[dict]:
+               enabled: bool | None = None, hw=None,
+               params=None) -> list[dict]:
     """The per-group routing table for a compiled design, as plain data
     (what the artifact exporter and the CLI ``--profile`` report).  Group
     membership mirrors ``lowering.fusion_groups`` without mutating task
-    ``fused_group`` ids; the cost gate (and tuning DB) apply exactly as in
+    ``fused_group`` ids; the cost gate applies exactly as in
     :func:`route_groups`."""
     from .artifact import _fifo_groups  # jax-free, same grouping
     from .costmodel import routing_params
@@ -464,7 +441,7 @@ def route_plan(graph: DataflowGraph, impl: dict[str, str], *,
         if enabled and names:
             for pat, tasks in match_group(graph, names, impl):
                 route = decide_route(graph, tasks, pat, hw=hw,
-                                     params=params, db=db)
+                                     params=params)
                 (routes if route.routed else rejected).append(route)
         kernel = ("pallas:" + "+".join(r.kernel for r in routes)
                   if routes else XLA_FUSED)
@@ -477,14 +454,12 @@ def route_plan(graph: DataflowGraph, impl: dict[str, str], *,
 def routing_state_key() -> tuple:
     """Every process-global switch a routing decision can depend on — the
     lowering memo key ingredient.  Covers the disable/force escape
-    hatches, the pattern-registry epoch, the priced backend, the active
-    calibration constants, and the tuning-database contents: flipping any
-    of them must never serve a stale program."""
+    hatches, the pattern-registry epoch, the priced backend and its gate
+    constants: flipping any of them must never serve a stale program."""
     from .costmodel import routing_backend, routing_params
-    from .tuning import default_tuning_db
     backend = routing_backend()
     return (pallas_disabled(), pallas_forced(), routing_epoch(), backend,
-            routing_params(backend).digest(), default_tuning_db().digest())
+            routing_params(backend).digest())
 
 
 __all__ = ["KernelPattern", "ROUTED_DECISIONS", "RoutedKernel", "XLA_FUSED",

@@ -121,21 +121,7 @@ def _scale_of(ews) -> float:
     return c
 
 
-def tiles(graph, tasks):
-    """(block_q, block_k) candidates; ``None`` = divisor-derived default."""
-    if kernel_mode() == "reference":
-        return [None]
-    q_shape = graph.buffers[tasks[0].spec.ins[0]].shape
-    sk = graph.buffers[tasks[0].spec.ins[1]].shape[-1]
-    sq = q_shape[-2]
-    out = [None]
-    for bq, bk in ((64, 64), (128, 128)):
-        if sq % bq == 0 and sk % bk == 0:
-            out.append({"block_q": bq, "block_k": bk})
-    return out
-
-
-def factory(graph, group, tasks, tile=None):
+def factory(graph, group, tasks):
     import jax
     import jax.numpy as jnp
 
@@ -148,9 +134,7 @@ def factory(graph, group, tasks, tile=None):
     sk = graph.buffers[kt_buf].shape[-1]
     c = _scale_of(ews)
     causal = _is_causal(ews)
-    tile = tile or {}
-    bq = int(tile.get("block_q", pow2_block(sq)))
-    bk = int(tile.get("block_k", pow2_block(sk)))
+    bq, bk = pow2_block(sq), pow2_block(sk)
 
     mode = kernel_mode()
     if mode == "pallas" and not step_vmem_ok(hd, bq, bk):
@@ -206,6 +190,6 @@ def register() -> None:
     register_kernel_pattern(KernelPattern(
         name="flashattn.mha",
         pattern=("matmul", "*ewise", "softmax", "matmul"),
-        factory=factory, feasible=_feasible, tiles=tiles,
+        factory=factory, feasible=_feasible,
         description="full softmax(c·q@kᵀ)@v chain via online-softmax "
                     "streaming (supersedes the softmaxmm tail)"))

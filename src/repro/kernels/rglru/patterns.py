@@ -27,16 +27,7 @@ def _feasible(graph, tasks) -> bool:
     return all_f32(graph, a_buf, b_buf, out_buf)
 
 
-def tiles(graph, tasks):
-    """Chunk-length candidates; ``None`` = divisor-derived default."""
-    if kernel_mode() == "reference":
-        return [None]
-    s = graph.buffers[tasks[0].spec.ins[0]].shape[1]
-    return [None] + [{"chunk": ch} for ch in (64, 128)
-                     if ch < s and s % ch == 0]
-
-
-def factory(graph, group, tasks, tile=None):
+def factory(graph, group, tasks):
     import jax
 
     (t,) = tasks
@@ -54,8 +45,7 @@ def factory(graph, group, tasks, tile=None):
         fn = jax.jit(rglru_ref)
     else:
         from .rglru import rglru_scan
-        chunk = int((tile or {}).get("chunk", pow2_block(s)))
-        fn = jax.jit(functools.partial(rglru_scan, chunk=chunk,
+        fn = jax.jit(functools.partial(rglru_scan, chunk=pow2_block(s),
                                        interpret=(mode == "interpret")))
 
     def run(env):
@@ -75,7 +65,7 @@ def register() -> None:
     _REGISTERED = True
     register_kernel_pattern(KernelPattern(
         name="rglru.scan", pattern=("scan",),
-        factory=factory, feasible=_feasible, tiles=tiles,
+        factory=factory, feasible=_feasible,
         allow_single=True,
         description="chunked RG-LRU linear recurrence h=a·h+b "
                     "(replaces the sequential generic scan)"))

@@ -40,7 +40,7 @@ def step_vmem_ok(states_shape) -> bool:
                    state)
 
 
-def factory(graph, group, tasks, tile=None):
+def factory(graph, group, tasks):
     import jax
 
     (t,) = tasks

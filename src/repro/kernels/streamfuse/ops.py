@@ -93,7 +93,7 @@ def _conv_feasible(graph, tasks) -> bool:
     return all_f32(graph, x_buf, w_buf, relu_t.spec.outs[0])
 
 
-def _conv_factory(graph, group, tasks, tile=None):
+def _conv_factory(graph, group, tasks):
     import jax
 
     pad_t, conv_t, relu_t = tasks
@@ -149,18 +149,7 @@ def _ew_applier(ew_tasks):
     return ew
 
 
-def _mm_chain_tiles(graph, tasks):
-    """Autotune candidates: row-block sizes for the streamed activation
-    (``None`` = the kernel's 128 default).  Reference mode has no blocking
-    to sweep — routed-vs-generic is the only question there."""
-    if kernel_mode() == "reference":
-        return [None]
-    m = graph.buffers[tasks[0].spec.ins[0]].shape[0]
-    return [None] + [{"block_m": b} for b in (64, 256)
-                     if b <= max(m, 64)]
-
-
-def _mm_chain_factory(graph, group, tasks, tile=None):
+def _mm_chain_factory(graph, group, tasks):
     import jax
     from .chain import fused_matmul_chain
 
@@ -177,9 +166,8 @@ def _mm_chain_factory(graph, group, tasks, tile=None):
     if mode == "reference":
         fn = jax.jit(lambda a, w1, w2: matmul_chain_ref(a, w1, w2, ew))
     else:
-        block_m = int((tile or {}).get("block_m", 128))
         fn = jax.jit(functools.partial(fused_matmul_chain, ew=ew,
-                                       block_m=block_m,
+                                       block_m=128,
                                        interpret=(mode == "interpret")))
 
     def run(env):
@@ -247,15 +235,7 @@ def _grad_ew_applier(tail_tasks):
     return ew, extra_bufs
 
 
-def _mm_grad_tiles(graph, tasks):
-    if kernel_mode() == "reference":
-        return [None]
-    m = graph.buffers[tasks[0].spec.ins[0]].shape[0]
-    return [None] + [{"block_m": b} for b in (64, 256)
-                     if b <= max(m, 64)]
-
-
-def _mm_grad_factory(graph, group, tasks, tile=None):
+def _mm_grad_factory(graph, group, tasks):
     import jax
     from .chain import fused_matmul_grad
 
@@ -270,9 +250,8 @@ def _mm_grad_factory(graph, group, tasks, tile=None):
     if mode == "reference":
         fn = jax.jit(lambda a, w, *ex: matmul_grad_ref(a, w, ex, ew))
     else:
-        block_m = int((tile or {}).get("block_m", 128))
         fn = jax.jit(functools.partial(fused_matmul_grad, ew=ew,
-                                       block_m=block_m,
+                                       block_m=128,
                                        interpret=(mode == "interpret")))
 
     def run(env):
@@ -305,20 +284,7 @@ def _softmax_mm_feasible(graph, tasks) -> bool:
     return all_f32(graph, sm.spec.ins[0], v_buf, mm.spec.outs[0])
 
 
-def _softmax_mm_tiles(graph, tasks):
-    """Autotune candidates: (row, contraction) block pairs for the online
-    softmax·V stream (``None`` = the kernel's 128/128 default)."""
-    if kernel_mode() == "reference":
-        return [None]
-    s, k = graph.buffers[tasks[0].spec.ins[0]].shape
-    out = [None]
-    for bm, bk in ((64, 128), (128, 256)):
-        if bm <= max(s, 64) and bk <= max(k, 128):
-            out.append({"block_m": bm, "block_k": bk})
-    return out
-
-
-def _softmax_mm_factory(graph, group, tasks, tile=None):
+def _softmax_mm_factory(graph, group, tasks):
     import jax
     from .chain import fused_softmax_matmul
 
@@ -331,11 +297,8 @@ def _softmax_mm_factory(graph, group, tasks, tile=None):
     if mode == "reference":
         fn = jax.jit(softmax_matmul_ref)
     else:
-        tile = tile or {}
         fn = jax.jit(functools.partial(
-            fused_softmax_matmul,
-            block_m=int(tile.get("block_m", 128)),
-            block_k=int(tile.get("block_k", 128)),
+            fused_softmax_matmul, block_m=128, block_k=128,
             interpret=(mode == "interpret")))
 
     def run(env):
@@ -366,16 +329,13 @@ def register() -> None:
     register_kernel_pattern(KernelPattern(
         name="streamfuse.mmchain", pattern=("matmul", "*ewise", "matmul"),
         factory=_mm_chain_factory, feasible=_mm_chain_feasible,
-        tiles=_mm_chain_tiles,
         description="ew(a@w1)@w2 with the activation row-block in VMEM"))
     register_kernel_pattern(KernelPattern(
         name="streamfuse.softmaxmm", pattern=("softmax", "matmul"),
         factory=_softmax_mm_factory, feasible=_softmax_mm_feasible,
-        tiles=_softmax_mm_tiles,
         description="online-softmax(s)@v streaming attention tail"))
     register_kernel_pattern(KernelPattern(
         name="streamfuse.mmgrad", pattern=("matmul", "*ewise"),
         factory=_mm_grad_factory, feasible=_mm_grad_feasible,
-        tiles=_mm_grad_tiles,
         description="cotangent matmul with fused gradient epilogue "
                     "(backward chains)"))

@@ -20,10 +20,10 @@ millions-of-users story (ROADMAP item 2).  A :class:`ServingRuntime` owns
   the way it is for the compile-only pool in ``core/compiler.py``).
   Host backends only: a TPU belongs to one process, so the runtime
   refuses ``workers > 0`` there and serves in-process.
-  Workers share the disk compile cache and the ``TuningDB`` sidecar via
-  environment passed at spawn; a crashed worker breaks the pool, the
-  runtime **respawns** it and retries the affected requests (bounded by
-  ``max_retries``, then a clean :class:`ServeError` on the future).
+  Workers share the disk compile cache via environment passed at spawn;
+  a crashed worker breaks the pool, the runtime **respawns** it and
+  retries the affected requests (bounded by ``max_retries``, then a clean
+  :class:`ServeError` on the future).
 * **hot-swap**: :meth:`ServingRuntime.swap` loads a new artifact via
   ``codo.load``, warms it, then atomically flips the serving handle —
   requests already dispatched drain on the old design; queued and new
@@ -434,7 +434,6 @@ class ServingRuntime:
                 env = {
                     "CODO_CACHE_DIR": getattr(self.cache, "disk_dir", "")
                     and str(self.cache.disk_dir),
-                    "CODO_TUNING_DB": os.environ.get("CODO_TUNING_DB", ""),
                     "CODO_SERVE_FAULT":
                         os.environ.get("CODO_SERVE_FAULT", ""),
                 }
@@ -562,10 +561,9 @@ _WORKER_PROGRAMS: dict = {}
 
 def _serve_worker_init(env: dict) -> None:
     """Runs once in each spawned worker: point this process at the shared
-    disk compile cache and tuning-DB sidecar before any codo import binds
-    its defaults.  The worker's jax platform is whatever it inherits from
-    the parent's environment; ``ServingRuntime`` refuses workers on a TPU
-    backend."""
+    disk compile cache before any codo import binds its defaults.  The
+    worker's jax platform is whatever it inherits from the parent's
+    environment; ``ServingRuntime`` refuses workers on a TPU backend."""
     for k, v in env.items():
         if v:
             os.environ[k] = v

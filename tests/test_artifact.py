@@ -214,6 +214,42 @@ def test_unknown_fields_warn_everywhere():
     assert any("graph.tasks[0].novel_task_field" in m for m in msgs)
 
 
+def test_old_tuning_section_is_read_and_ignored():
+    """Documents from exporters that still wrote measured routing records
+    (the optional v1.2 ``tuning`` section) validate with a note, route
+    exactly as the clean document does, and re-export without it."""
+    from repro.kernels import register_all
+    register_all()
+    c = codo_opt(dm.feed_forward(64, 256), CodoOptions(budget_units=64),
+                 cache=None)
+    lower(c, jit=False)
+    clean = export_artifact(c)
+    assert "tuning" not in clean
+    old = json.loads(json.dumps(clean))
+    old["tuning"] = {"entries": [
+        {"signature": "0123456789abcdef", "backend": "cpu", "hw": "v5e",
+         "pattern": "streamfuse.mmchain", "choice": "xla-fused",
+         "tile": {"block_m": 64}, "routed_ms": 2.0, "generic_ms": 1.0,
+         "workload": clean["graph"]["name"], "tasks": []}]}
+
+    notes = validate_artifact(old)
+    assert [n for n in notes if n.startswith("tuning:")]
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        imported = import_artifact(old)
+    assert any(str(x.message).startswith("tuning:") for x in w
+               if issubclass(x.category, ArtifactWarning))
+
+    def routes(design):
+        return [(r.kernel, list(r.tasks), r.decision)
+                for g in lower(design, jit=False).groups
+                for r in (*g.routes, *g.rejected)]
+
+    want = routes(import_artifact(clean))
+    assert want and routes(imported) == want
+    assert "tuning" not in export_artifact(imported)
+
+
 def test_integrity_and_fusion_cross_checks():
     doc = export_artifact(_compile_block())
 

@@ -1,48 +1,29 @@
-"""Cost-gated routing + measured autotuning (the ISSUE-6 tentpole).
+"""Cost-gated routing: the gate is the only place a kernel is decided.
 
-Covers the latency predictor against the recorded routing bench (rank
-agreement, not absolute cycles), the CPU softmaxmm fallback the bench
-motivated, the ``tuned > forced > predicted`` decision precedence with
-its env overrides, the tuning database's ride through artifact v1.2 into
-a fresh interpreter, and the lowering memo key's sensitivity to tuning
-changes.  Kernel numerics and the matcher itself live in
-``tests/test_routing.py``.
+Covers the CPU softmaxmm fallback the committed constants encode, the
+``forced > predicted`` decision precedence with its env overrides, the
+hardware the gate prices on a TPU, and the routes the gate keeps in each
+benchmark cell when pricing and building as on a v5e.  Kernel numerics
+and the matcher itself live in ``tests/test_routing.py``.
 """
 
+import importlib.util
 import json
-import os
-import subprocess
 import sys
-import textwrap
 from pathlib import Path
 
 import pytest
 
 from repro.core import CodoOptions, codo_opt
-from repro.core.costmodel import estimate_chain, routing_backend, \
-    routing_params
-from repro.core.lowering import (LOWER_CACHE_STATS, clear_lower_cache,
-                                 fusion_groups, lower)
-from repro.core.routing import ROUTED_DECISIONS, XLA_FUSED, match_group
-from repro.core.tuning import (TuningRecord, autotune_compiled,
-                               chain_signature, default_tuning_db,
-                               reset_default_tuning_db)
+from repro.core.costmodel import routing_backend, routing_params
+from repro.core.lowering import lower
+from repro.core.routing import ROUTED_DECISIONS, XLA_FUSED
 from repro.kernels import register_all
 from repro.models import dataflow_models as dm
 
 register_all()
 
 REPO = Path(__file__).resolve().parents[1]
-BENCH_JSON = REPO / "results" / "bench" / "routing_groups.json"
-
-
-@pytest.fixture(autouse=True)
-def _clean_tuning_db():
-    """Measured decisions override the predictor, so every test here gets
-    (and leaves behind) an empty process tuning database."""
-    reset_default_tuning_db()
-    yield
-    reset_default_tuning_db()
 
 
 def _compile(graph, budget=64):
@@ -55,72 +36,17 @@ def _compile(graph, budget=64):
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.skipif(not BENCH_JSON.exists(),
-                    reason="no recorded routing bench")
-def test_predictor_ranks_chains_like_recorded_bench():
-    """The gate doesn't need cycle-accurate latencies — it needs the
-    *ordering* of chains by cost to agree with what the machine measured.
-
-    Judged as pairwise rank concordance within each workload, and only
-    over pairs where both sides show a real margin: resnet stages are
-    constant-FLOPs by design, so the model prices them as near-ties that
-    CPU wall-clock (which favors large-spatial layers) legitimately
-    scrambles; and the cycle→ms scale differs per op family, so
-    cross-workload pairs are not comparable."""
-    doc = json.loads(BENCH_JSON.read_text())
-    quick = bool(doc.get("quick"))
-    builds = {
-        "gpt2_block": (lambda: dm.gpt2_block(S=64)) if quick
-        else (lambda: dm.gpt2_block()),
-        "resnet18": lambda: dm.resnet18(32),
-    }
-    predicted = {}
-    for wname, build in builds.items():
-        c = codo_opt(build(), CodoOptions.preset("opt5"), cache=None)
-        impl = c.buffer_plan.impl if c.buffer_plan else {}
-        for g in fusion_groups(c.graph, impl):
-            for pat, tasks in match_group(c.graph, g.tasks, impl):
-                est = estimate_chain(c.graph, tasks, pat.name)
-                key = (wname, tuple(t.name for t in tasks))
-                predicted[key] = est.generic_cycles
-    points = []
-    for r in doc["records"]:
-        key = (r["workload"], tuple(r["tasks"]))
-        if key in predicted:
-            points.append((r["workload"], predicted[key],
-                           float(r["xla_ms"])))
-    assert len(points) >= 6, "bench records no longer line up with matcher"
-
-    judged = concordant = 0
-    for i in range(len(points)):
-        for j in range(i + 1, len(points)):
-            (wa, pa, ma), (wb, pb, mb) = points[i], points[j]
-            if wa != wb:
-                continue
-            if max(pa, pb) < 1.2 * min(pa, pb):      # predicted near-tie
-                continue
-            if max(ma, mb) < 1.3 * min(ma, mb):      # measured noise band
-                continue
-            judged += 1
-            concordant += (pa > pb) == (ma > mb)
-    assert judged >= 5, f"only {judged} decisive pairs"
-    assert concordant / judged >= 0.8, \
-        f"predictor agrees on {concordant}/{judged} decisive pairs"
-
-
 def test_softmaxmm_tail_stays_generic_on_cpu(monkeypatch):
-    """The satellite bugfix, pinned: the bench measures the softmaxmm
-    kernel at ~0.97x on CPU, so the calibrated gate must route the
-    attention tail to generic XLA there — at any size.  In gpt2_block the
-    full-chain ``flashattn.mha`` now supersedes this tail (see
-    test_flashattn_supersedes_softmaxmm_in_gpt2), so the bare tail is
-    exercised on a graph whose chain *starts* at the softmax."""
+    """The softmaxmm kernel's CPU constant is 0.97x, so the gate must
+    route the attention tail to generic XLA there — at any size.  In
+    gpt2_block the full-chain ``flashattn.mha`` now supersedes this tail
+    (see test_flashattn_supersedes_softmaxmm_in_gpt2), so the bare tail
+    is exercised on a graph whose chain *starts* at the softmax."""
     monkeypatch.delenv("CODO_FORCE_PALLAS", raising=False)
     monkeypatch.delenv("CODO_DISABLE_PALLAS", raising=False)
-    monkeypatch.delenv("CODO_ROUTING_CALIBRATION", raising=False)
     monkeypatch.setenv("CODO_BACKEND", "cpu")
     # CPU has no spill/overlap terms, so the win condition reduces to
-    # eff * (1 + slack) > 1; softmaxmm's calibrated 0.97 keeps it losing
+    # eff * (1 + slack) > 1; softmaxmm's committed 0.97 keeps it losing
     # regardless of chain size.
     p = routing_params("cpu")
     assert p.eff("streamfuse.softmaxmm") * (1.0 + p.slack) < 1.0
@@ -144,20 +70,6 @@ def test_softmaxmm_tail_stays_generic_on_cpu(monkeypatch):
                    for rr in e["rejected"]) for e in entries)
 
 
-def test_calibration_env_knob_refits_efficiency(monkeypatch, tmp_path):
-    doc = {"backend": "cpu", "records": [
-        {"kernel": "streamfuse.softmaxmm", "speedup": 1.5},
-        {"kernel": "streamfuse.softmaxmm", "speedup": 1.5},
-    ]}
-    path = tmp_path / "calib.json"
-    path.write_text(json.dumps(doc))
-    monkeypatch.setenv("CODO_ROUTING_CALIBRATION", str(path))
-    p = routing_params("cpu")
-    assert p.eff("streamfuse.softmaxmm") == pytest.approx(1.5, rel=1e-3)
-    # patterns absent from the document keep their defaults
-    assert p.eff("streamfuse.conv") == pytest.approx(0.99)
-
-
 # --------------------------------------------------------------------------
 # Decision precedence + env overrides
 # --------------------------------------------------------------------------
@@ -177,147 +89,6 @@ def test_force_and_disable_override_precedence(monkeypatch):
     assert all(not g.routes for g in low2.groups)
     assert all(g.kernel == XLA_FUSED for g in low2.groups)
     assert any(g.decision == "disabled" for g in low2.groups)
-
-
-def test_tuning_db_change_flips_memo_key_and_decision():
-    """A measured entry must (a) override the predictor's verdict and
-    (b) change the lowering memo key, so stale programs built before the
-    measurement can never be served after it."""
-    c = _compile(dm.feed_forward(16, 32))
-    lower(c, jit=False)          # assigns fused_group ids (hash settles)
-    clear_lower_cache()
-    low = lower(c, jit=False)
-    assert LOWER_CACHE_STATS["misses"] == 1
-    rej = [r for g in low.groups for r in g.rejected
-           if r.kernel == "streamfuse.mmchain"]
-    assert rej and rej[0].decision == "predicted-loss"
-    lower(c, jit=False)                      # same key: a hit
-    assert LOWER_CACHE_STATS["hits"] == 1
-
-    tasks = [c.graph.task(n) for n in rej[0].tasks]
-    default_tuning_db().update(TuningRecord(
-        signature=chain_signature(c.graph, tasks),
-        backend=routing_backend(), hw=c.options.hw.name,
-        pattern="streamfuse.mmchain", choice="pallas",
-        routed_ms=1.0, generic_ms=2.0))
-    low2 = lower(c, jit=False)               # digest changed: re-lower
-    assert LOWER_CACHE_STATS["misses"] == 2
-    tuned = [r for g in low2.groups for r in g.routes
-             if r.kernel == "streamfuse.mmchain"]
-    assert tuned and tuned[0].decision == "tuned"
-    assert tuned[0].measured_speedup == pytest.approx(2.0)
-
-    reset_default_tuning_db()                # back to the empty-db digest:
-    low3 = lower(c, jit=False)               # the pre-tuning entry is reused
-    assert LOWER_CACHE_STATS["hits"] == 2
-    assert all(r.kernel != "streamfuse.mmchain"
-               for g in low3.groups for r in g.routes)
-
-
-# --------------------------------------------------------------------------
-# Measured autotune riding artifact v1.2 into a fresh interpreter
-# --------------------------------------------------------------------------
-
-
-def _fresh_interpreter(code: str) -> subprocess.CompletedProcess:
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
-    for k in ("CODO_TUNING_DB", "CODO_FORCE_PALLAS", "CODO_DISABLE_PALLAS"):
-        env.pop(k, None)
-    return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                          capture_output=True, text=True, env=env)
-
-
-def test_tuning_roundtrips_through_artifact_in_fresh_interpreter(tmp_path):
-    from repro.core import export_artifact
-    c = _compile(dm.feed_forward(16, 32))
-    lower(c, jit=False)
-    records = autotune_compiled(c, repeats=2, warmup=1)
-    assert records and len(default_tuning_db()) >= 1
-    assert all(r.choice in ("pallas", XLA_FUSED) for r in records)
-
-    doc = export_artifact(c)
-    assert doc["schema_version"] == "1.5"
-    assert doc["tuning"] and len(doc["tuning"]["entries"]) >= 1
-    path = tmp_path / "ff.json"
-    path.write_text(json.dumps(doc))
-
-    proc = _fresh_interpreter(f"""
-        import json
-        from repro.core import import_artifact
-        from repro.core.lowering import lower
-        from repro.core.tuning import default_tuning_db
-        from repro.models.dataflow_models import random_inputs
-
-        doc = json.loads(open({str(path)!r}).read())
-        assert len(default_tuning_db()) == 0
-        c = import_artifact(doc)
-        db = default_tuning_db()
-        want = {{e["signature"] for e in doc["tuning"]["entries"]}}
-        got = {{r.signature for r in db.entries.values()}}
-        assert want <= got, (want, got)
-        # the imported measurement drives routing in this process too
-        low = lower(c, jit=False)
-        decisions = {{r.decision for g in low.groups
-                      for r in (*g.routes, *g.rejected)}}
-        assert decisions & {{"tuned", "tuned-generic"}}, decisions
-        low(random_inputs(c.graph))              # still executes
-        print("ROUNDTRIP-OK", len(db))
-    """)
-    assert proc.returncode == 0, proc.stderr
-    assert "ROUNDTRIP-OK" in proc.stdout
-
-
-def test_gate_retry_remeasures_only_offenders(monkeypatch):
-    """The CI gate re-times first-pass offenders solo at a higher
-    best-of count and judges the fresh numbers — a noise blip converges
-    back within tolerance, a real regression fails twice."""
-    from benchmarks import routing_bench as rb
-
-    def rec(workload, gid, kernel, speedup, routed=True):
-        return {"workload": workload, "gid": gid, "kernel": kernel,
-                "tasks": ["a", "b"], "decision": "predicted-win",
-                "routed": routed, "speedup": speedup,
-                "pallas_ms": 1.0, "xla_ms": speedup,
-                "predicted_speedup": 1.0,
-                "predicted_routed_cycles": 1.0,
-                "predicted_generic_cycles": 1.0}
-
-    doc = {"backend": "cpu", "tolerance": 0.05, "quick": True,
-           "records": [rec("resnet18", 1, "streamfuse.conv", 1.02),
-                       rec("resnet18", 6, "streamfuse.conv", 0.92),
-                       rec("gpt2_block", 0, "streamfuse.softmaxmm",
-                           0.80, routed=False)]}
-    # Only the routed under-tolerance record is an offender; the
-    # rejected softmaxmm chain is measured but never judged.
-    assert len(rb.check_gate(doc)) == 1
-
-    seen = []
-
-    def fake_bench(name, build, *, warmup, reps, only=None):
-        seen.append((name, reps, only))
-        return [rec(name, gid, kernel, 0.99)
-                for gid, kernel, _tasks in sorted(only)]
-
-    monkeypatch.setattr(rb, "bench_workload", fake_bench)
-    doc = rb.remeasure_offenders(doc)
-    # One solo re-run, offender only, at the recheck best-of count.
-    assert seen == [("resnet18", rb.RECHECK_REPS,
-                     {(6, "streamfuse.conv", ("a", "b"))})]
-    by_gid = {r["gid"]: r for r in doc["records"]
-              if r["workload"] == "resnet18"}
-    assert by_gid[6]["speedup"] == 0.99      # patched in
-    assert by_gid[1]["speedup"] == 1.02      # untouched
-    assert rb.check_gate(doc) == []
-
-    # A repeat offender stays failed.
-    doc["records"][1]["speedup"] = 0.90
-    monkeypatch.setattr(
-        rb, "bench_workload",
-        lambda name, build, *, warmup, reps, only=None:
-        [rec(name, gid, kernel, 0.90)
-         for gid, kernel, _tasks in sorted(only)])
-    doc = rb.remeasure_offenders(doc)
-    assert len(rb.check_gate(doc)) == 1
 
 
 class _Dev:
@@ -345,3 +116,58 @@ def test_routing_backend_does_not_swallow_import_errors(monkeypatch):
     monkeypatch.setitem(sys.modules, "jax", None)
     with pytest.raises(ImportError):
         routing_backend()
+
+
+# --------------------------------------------------------------------------
+# The chip's routing, per benchmark cell
+# --------------------------------------------------------------------------
+
+
+def _bench_config(name: str):
+    """A benchmark configuration's builder module and its JSON spec, read
+    from ``bench/configs`` as the benchmark harness reads them."""
+    path = REPO / "bench" / "configs" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        "cell_" + path.stem.replace("-", "_").replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod, json.loads(path.with_suffix(".json").read_text())
+
+
+@pytest.fixture
+def on_v5e(monkeypatch):
+    """Price and build as on a TPU v5e: the factories choose their
+    compiled (``pallas``) mode, so their VMEM guards apply, and the gate
+    prices the chip jax reports."""
+    import jax
+    from repro.kernels.common import kernel_mode
+    for k in ("CODO_PALLAS_INTERPRET", "CODO_FORCE_PALLAS",
+              "CODO_DISABLE_PALLAS", "CODO_BACKEND"):
+        monkeypatch.delenv(k, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev("TPU v5 lite")])
+    assert kernel_mode() == "pallas" and routing_backend() == "tpu"
+
+
+@pytest.mark.parametrize("cell,want", [
+    ("gpt2m-fwd", 1), ("resnet18-fwd", 5), ("gpt2m-train", 1),
+    ("granite-h-prefill-4k", 10)])
+def test_chip_routes_per_benchmark_cell(on_v5e, cell, want):
+    """The routes ``lower()`` keeps in each cell's program at the cell's
+    own shapes, counted as the harness counts ``setup.routes``: every
+    route of the forward program, or of all three train-step phases."""
+    import repro.api as codo
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    w = next(w for w in bench["workloads"] if w["name"] == cell)
+    mod, spec = _bench_config(w["config"])
+    if w["traffic"] == "train-closed":
+        step = codo.compile(mod.loss, *map(tuple, spec["train_inputs"]),
+                            grad=True, opt=spec["optimizer"], cache=None)
+        programs = [step.forward, step.backward, step.update]
+    else:
+        programs = [codo.compile(mod.block, *map(tuple, spec["inputs"]),
+                                 cache=None)]
+    routes = [r for p in programs for g in p.lower(jit=True).groups
+              for r in g.routes]
+    assert len(routes) == want, [r.kernel for r in routes]
+    assert all(r.decision == "predicted-win" for r in routes)

@@ -7,7 +7,7 @@ path), their ``rejected[]``/``RouteDecision`` diagnostics under the
 un-forced CPU cost gate, the TPU parameterisation that flips those same
 chains to predicted wins, and the pattern-registry epoch riding the
 lowering memo key.  Kernel numerics live in ``tests/test_kernels.py``;
-the gate's calibration in ``tests/test_costmodel_routing.py``.
+the gate's constants in ``tests/test_costmodel_routing.py``.
 """
 
 import pytest
@@ -120,13 +120,12 @@ def test_recurrence_family_true_pallas_interpret(monkeypatch, wname, build,
 @pytest.mark.parametrize("wname,build,kernel", FAMILIES)
 def test_cpu_gate_rejects_with_full_diagnostics(monkeypatch, wname, build,
                                                 kernel):
-    """On CPU the scan kernels are calibrated below break-even and the
+    """On CPU the scan kernels' constants sit below break-even and the
     default mha_batched shape sits under the flashattn win threshold, so
     each chain lands in ``rejected[]`` as a fully-priced RouteDecision —
     not silently dropped by the matcher."""
     monkeypatch.delenv("CODO_FORCE_PALLAS", raising=False)
     monkeypatch.delenv("CODO_DISABLE_PALLAS", raising=False)
-    monkeypatch.delenv("CODO_ROUTING_CALIBRATION", raising=False)
     monkeypatch.setenv("CODO_BACKEND", "cpu")
     c = _compile(build())
     low = lower(c, jit=False)

@@ -21,8 +21,7 @@ from repro.core.compiler import main as compiler_main
 from repro.core.lowering import (LOWER_CACHE_STATS, clear_lower_cache,
                                  fusion_groups, lower, verify_routing)
 from repro.core.routing import (XLA_FUSED, KernelPattern, match_group,
-                                pallas_disabled, registered_patterns,
-                                route_plan)
+                                pallas_disabled, route_plan)
 from repro.kernels import register_all
 from repro.kernels.streamfuse import (fused_matmul_chain,
                                       fused_softmax_matmul,
@@ -138,13 +137,6 @@ def test_chain_operand_reuse_does_not_route():
 def test_wildcard_cannot_anchor_pattern():
     with pytest.raises(ValueError, match="wildcard"):
         KernelPattern("bad", ("*ewise", "matmul"), factory=lambda *a: None)
-
-
-def test_legacy_register_group_kernel_shim():
-    from repro.core.lowering import register_group_kernel
-    register_group_kernel(("pool", "pool", "pool"), lambda graph, group: None)
-    names = {p.name: p for p in registered_patterns()}
-    assert names["pool+pool+pool"].pattern == ("pool", "pool", "pool")
 
 
 # --------------------------------------------------------------------------
